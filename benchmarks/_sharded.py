@@ -12,7 +12,8 @@ pieces they share so validation/error text never diverges:
   must land before the first jax array op.
 * :func:`leg_tag` -- the ``@8dev`` / ``@4x2dev`` CSV-row suffix.
 * :func:`backend_ready` -- False when something initialized the backend
-  before the flag landed (the leg then reports SKIPPED instead of lying).
+  before the flag landed (the benchmark then exits non-zero instead of
+  reporting a leg it did not run).
 """
 from __future__ import annotations
 

@@ -1,33 +1,30 @@
 """Paper-figure benchmarks (one per paper table/figure).
 
-Uses scripts/out/paper_artifacts.json (the full-scale background run) when
-present; otherwise quick-trains at REPRO_BENCH_EPISODES (default 200) so
-``python -m benchmarks.run`` is self-contained.
+The artifact is built in this process by ``scripts/train_compare.py``'s
+``build_artifacts`` at REPRO_BENCH_EPISODES (default 200) episodes, so
+``python -m benchmarks.run`` is self-contained and reads no file it did not
+write.
 """
 from __future__ import annotations
 
-import json
+import importlib.util
 import os
 
 import numpy as np
 
-_CACHE = os.path.join(os.path.dirname(__file__), "..", "scripts", "out",
-                      "paper_artifacts.json")
+_TRAIN_COMPARE = os.path.join(os.path.dirname(__file__), "..", "scripts",
+                              "train_compare.py")
 
 
-def load_or_build(episodes: int | None = None) -> dict:
-    if os.path.exists(_CACHE) and episodes is None:
-        with open(_CACHE) as f:
-            return json.load(f)
-    import subprocess
-    import sys
+def build(episodes: int | None = None) -> dict:
+    """Train and evaluate the paper's agents in-process; returns the
+    Figs. 3-5 artifact dict."""
+    spec = importlib.util.spec_from_file_location("train_compare",
+                                                  _TRAIN_COMPARE)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
     eps = episodes or int(os.environ.get("REPRO_BENCH_EPISODES", "200"))
-    subprocess.run([sys.executable,
-                    os.path.join(os.path.dirname(__file__), "..", "scripts",
-                                 "train_compare.py"), str(eps)],
-                   check=True)
-    with open(_CACHE) as f:
-        return json.load(f)
+    return mod.build_artifacts(eps)
 
 
 def fig3_convergence(art: dict):

@@ -91,6 +91,8 @@ def main(argv=None) -> int:
                          "paper-figure, ablation, and roofline legs)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     t_start = time.time()
     print("name,us_per_call,derived")
 
@@ -105,7 +107,7 @@ def main(argv=None) -> int:
     # -- paper figures -------------------------------------------------------
     from . import paper_figs
     t0 = time.time()
-    art = paper_figs.load_or_build()
+    art = paper_figs.build()
     build_us = (time.time() - t0) * 1e6
 
     for r in paper_figs.fig3_convergence(art):

@@ -160,23 +160,24 @@ def main(argv=None) -> int:
     if args.devices:
         tag = leg_tag(args.devices, args.model)
         if not backend_ready(args.devices):
-            print(f"scenario_grid_sharded[{grid.b}x{grid.n_ue}"
-                  f"{tag}],0,SKIPPED_backend_already_initialized")
-        else:
-            from repro.launch.mesh import make_cells_mesh
-            # Layout preconditions were validated pre-init; make_cells_mesh
-            # re-checks them and raises an actionable ValueError either way.
-            grid_sh = build_grid(args.cells, args.ues, args.seed)
-            grid_sh.use_mesh(make_cells_mesh(args.devices,
-                                             model=args.model))
-            dt_s, sps_s = bench_batched(grid_sh, args.policy, args.steps,
-                                        args.repeats)
-            print(f"scenario_grid_sharded[{grid.b}x{grid.n_ue}"
-                  f"{tag}],{dt_s*1e6:.0f},"
-                  f"slots_per_s={sps_s:.0f}")
-            print(f"scenario_grid_sharded_speedup[{grid.b}x{grid.n_ue}"
-                  f"{tag}],0,"
-                  f"sharded_over_batched={sps_s / sps_b:.2f}x")
+            print(f"error: the sharded leg asked for {args.devices} "
+                  f"devices but only {len(jax.devices())} are live (the "
+                  "backend initialized before the device flag landed)")
+            return 1
+        from repro.launch.mesh import make_cells_mesh
+        # Layout preconditions were validated pre-init; make_cells_mesh
+        # re-checks them and raises an actionable ValueError either way.
+        grid_sh = build_grid(args.cells, args.ues, args.seed)
+        grid_sh.use_mesh(make_cells_mesh(args.devices,
+                                         model=args.model))
+        dt_s, sps_s = bench_batched(grid_sh, args.policy, args.steps,
+                                    args.repeats)
+        print(f"scenario_grid_sharded[{grid.b}x{grid.n_ue}"
+              f"{tag}],{dt_s*1e6:.0f},"
+              f"slots_per_s={sps_s:.0f}")
+        print(f"scenario_grid_sharded_speedup[{grid.b}x{grid.n_ue}"
+              f"{tag}],0,"
+              f"sharded_over_batched={sps_s / sps_b:.2f}x")
 
     speedup = sps_b / sps_l
     print(f"scenario_grid_speedup[{grid.b}x{grid.n_ue}],0,"

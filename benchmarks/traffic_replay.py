@@ -223,26 +223,27 @@ def main(argv=None) -> int:
     if args.devices:
         tag = leg_tag(args.devices, args.model)
         if not backend_ready(args.devices):
-            print(f"traffic_replay_sharded[{grid.b}x{grid.n_ue}"
-                  f"{tag}],0,SKIPPED_backend_already_initialized")
-        else:
-            from repro.launch.mesh import make_cells_mesh
-            grid_sh = build_grid(trace, args.cells, args.seed)
-            grid_sh.use_mesh(make_cells_mesh(args.devices,
-                                             model=args.model))
-            dt_s, sps_s, sum_s = bench_batched(grid_sh, args.policy,
-                                               args.steps, args.repeats)
-            err_s = float(np.max(np.abs(
-                np.asarray(sum_s["reward"]) - np.asarray(summary["reward"]))
-                / np.maximum(np.abs(np.asarray(summary["reward"])), 1e-7)))
-            print(f"traffic_replay_sharded[{grid.b}x{grid.n_ue}{tag}],"
-                  f"{dt_s*1e6:.0f},slots_per_s={sps_s:.0f}")
-            print(f"traffic_replay_sharded_parity[{grid.b}x{grid.n_ue}"
-                  f"{tag}],0,max_rel_err={err_s:.2e}"
-                  f"_{'OK' if err_s < 1e-5 else 'FAIL'}")
-            if err_s >= 1e-5:
-                print("PARITY FAILURE: sharded and batched replays diverged")
-                return 1
+            print(f"error: the sharded leg asked for {args.devices} "
+                  f"devices but only {len(jax.devices())} are live (the "
+                  "backend initialized before the device flag landed)")
+            return 1
+        from repro.launch.mesh import make_cells_mesh
+        grid_sh = build_grid(trace, args.cells, args.seed)
+        grid_sh.use_mesh(make_cells_mesh(args.devices,
+                                         model=args.model))
+        dt_s, sps_s, sum_s = bench_batched(grid_sh, args.policy,
+                                           args.steps, args.repeats)
+        err_s = float(np.max(np.abs(
+            np.asarray(sum_s["reward"]) - np.asarray(summary["reward"]))
+            / np.maximum(np.abs(np.asarray(summary["reward"])), 1e-7)))
+        print(f"traffic_replay_sharded[{grid.b}x{grid.n_ue}{tag}],"
+              f"{dt_s*1e6:.0f},slots_per_s={sps_s:.0f}")
+        print(f"traffic_replay_sharded_parity[{grid.b}x{grid.n_ue}"
+              f"{tag}],0,max_rel_err={err_s:.2e}"
+              f"_{'OK' if err_s < 1e-5 else 'FAIL'}")
+        if err_s >= 1e-5:
+            print("PARITY FAILURE: sharded and batched replays diverged")
+            return 1
 
     # batched == looped parity on per-cell mean reward (identical keys)
     batched = np.asarray(summary["reward"])

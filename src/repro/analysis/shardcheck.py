@@ -353,9 +353,9 @@ def _check_donation(arch: str = "qwen3-0.6b") -> list[ShardFailure]:
     eng = ServingEngine(cfg, params, slots=2, s_max=32)
     failures = donation_failures(
         eng._decode_paged,
-        (eng._pool_state, jnp.zeros((eng.slots,), jnp.int32),
+        (eng.params, eng._pool_state, jnp.zeros((eng.slots,), jnp.int32),
          jnp.asarray(eng.block_tables), jnp.asarray(eng.seq_lens)),
-        arch=cfg.name, what="decode_step_paged tick update")
+        arch=cfg.name, what="decode_step_paged tick update", argnum=1)
     # the commit bridge: same donation contract on its state argument
     req = Request(rid=0, prompt=np.arange(5, dtype=np.int32), max_new=2)
     _, cache, pad = eng._solo_prefill(req)

@@ -4,7 +4,8 @@ Given the partitioning decision ``cut`` (from the DRL policy), the remaining
 continuous allocation decouples into three convex programs, solved exactly and
 jit-compatibly:
 
-* P3 (eq. 19)  local CPU frequency  f_ue  -- Fibonacci line search (paper) per UE
+* P3 (eq. 19)  local CPU frequency  f_ue  -- bisection on the slope's sign per UE
+               (the paper uses a Fibonacci search; see ``solve_p3``)
 * P4 (eq. 20)  edge CPU frequency   f_es  -- closed-form KKT water-filling (eq. 23)
 * P5 (eq. 24)  uplink bandwidth     alpha -- two-level KKT bisection (replaces CVX;
                see DESIGN.md "Hardware adaptation")
@@ -14,25 +15,16 @@ vectorize over UEs.  Log-domain comparisons keep P5 stable in float32.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 _EPS = 1e-12
 
 # ---------------------------------------------------------------------------
-# P3: local computational resource (Fibonacci search, eq. 19)
+# P3: local computational resource (eq. 19)
 # ---------------------------------------------------------------------------
 
-_FIB_N = 40
-_FIB = np.ones(_FIB_N + 3, dtype=np.float64)
-for _i in range(2, _FIB_N + 3):
-    _FIB[_i] = _FIB[_i - 1] + _FIB[_i - 2]
-# ratio[k] = F_{n-k} / F_{n-k+2}: fraction of the interval probed at step k.
-_FIB_RATIO_LO = np.array([_FIB[_FIB_N - k] / _FIB[_FIB_N - k + 2] for k in range(_FIB_N)])
-_FIB_RATIO_HI = np.array([_FIB[_FIB_N - k + 1] / _FIB[_FIB_N - k + 2] for k in range(_FIB_N)])
+_P3_ITERS = 40
 
 
 def p3_objective(f, q_energy, kappa, d_ue, lam, v):
@@ -46,7 +38,15 @@ def p3_objective(f, q_energy, kappa, d_ue, lam, v):
 
 
 def solve_p3(q_energy, kappa, d_ue, lam, v, f_max, *, stability_margin=1e-3):
-    """Fibonacci-search minimizer of (19) per UE on (d*lam, f_max].
+    """Minimizer of (19) per UE on [d*lam*(1+margin)+1, f_max].
+
+    (19) is convex there, so the minimizer is where its slope changes sign:
+    bisection on that sign.  The paper's Fibonacci search on the objective
+    VALUES pins f only to ~sqrt(eps) relative in float32 -- (19) is flat at
+    its minimum, so rounding noise decides the comparisons -- and two
+    compilations of the same step (a wider cell batch, another fusion)
+    then disagreed by 1e-5 in delay and energy.  The slope crosses zero
+    with nonzero gradient, so its sign pins f to a few ulps.
 
     Vectorized over leading UE axis.  UEs with ``d_ue == 0`` (full offload)
     get f_ue = 0.  The caller guarantees feasibility ``d*lam < f_max`` (C7,
@@ -55,27 +55,22 @@ def solve_p3(q_energy, kappa, d_ue, lam, v, f_max, *, stability_margin=1e-3):
     lo = d_ue * lam * (1.0 + stability_margin) + 1.0
     hi = jnp.full_like(lo, f_max)
     lo = jnp.minimum(lo, hi)
+    dl = d_ue * lam
 
-    obj = functools.partial(p3_objective, q_energy=q_energy, kappa=kappa,
-                            d_ue=d_ue, lam=lam, v=v)
+    def slope(f):
+        # f^2 * d(19)/df: same sign, and no f^4 term to overflow float32
+        r = d_ue / jnp.maximum(f - dl, _EPS)
+        return ((2.0 * q_energy * kappa * dl * f) * f * f - v * d_ue
+                - 0.5 * v * lam * r * r * (2.0 * f - dl))
 
-    ratio_lo = jnp.asarray(_FIB_RATIO_LO, dtype=lo.dtype)
-    ratio_hi = jnp.asarray(_FIB_RATIO_HI, dtype=lo.dtype)
-
-    def body(k, ab):
+    def body(_, ab):
         a, b = ab
-        span = b - a
-        x1 = a + ratio_lo[k] * span
-        x2 = a + ratio_hi[k] * span
-        f1, f2 = obj(x1), obj(x2)
-        take_left = f1 < f2
-        return jnp.where(take_left, a, x1), jnp.where(take_left, x2, b)
+        mid = 0.5 * (a + b)
+        rising = slope(mid) > 0
+        return jnp.where(rising, a, mid), jnp.where(rising, mid, b)
 
-    a, b = jax.lax.fori_loop(0, _FIB_N, body, (lo, hi))
-    f_star = 0.5 * (a + b)
-    # Also consider the upper boundary (optimum can sit at f_max when Q ~ 0).
-    f_star = jnp.where(obj(hi) < obj(f_star), hi, f_star)
-    return jnp.where(d_ue > 0, f_star, 0.0)
+    a, b = jax.lax.fori_loop(0, _P3_ITERS, body, (lo, hi))
+    return jnp.where(d_ue > 0, 0.5 * (a + b), 0.0)
 
 
 # ---------------------------------------------------------------------------

@@ -214,6 +214,20 @@ def constrain(tree, gs: GridSharding, *, lead: int = 0):
     return jax.tree.map(f, tree)
 
 
+def per_shard(fn, gs: GridSharding):
+    """``fn`` run once per cell shard: ``shard_map`` over the cells axis,
+    every argument and result split on its leading (cell) dim and whole
+    across any model axis.  GSPMD cannot partition a Mosaic (Pallas TPU)
+    kernel, so a kernel called on the grid's cell-sharded arrays goes
+    through here; cells are independent, so each shard's launch is exact.
+    """
+    spec = P(gs.axis)
+    # check_vma off: a pallas_call's out_shape carries no varying-axes
+    # annotation for shard_map to check
+    return jax.shard_map(fn, mesh=gs.mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)
+
+
 def unpad(tree, gs: GridSharding, *, lead: int = 0):
     """Slice the cell axis back to the logical b (inverse of pad_cells)."""
     if gs.pad == 0:

@@ -210,7 +210,8 @@ def oracle_cut_fn(env: MecEnv):
 # ---------------------------------------------------------------------------
 
 def run_fixed_batched(grid, policy="oracle", episodes: int = 1,
-                      steps: int = 200, seed: int = 0):
+                      steps: int = 200, seed: int = 0,
+                      oracle_backend: str = "auto"):
     """Batched analogue of :func:`run_fixed` over a ``ScenarioGrid``.
 
     ``policy`` is a ``scenarios.POLICIES`` name or a per-cell callable
@@ -223,9 +224,10 @@ def run_fixed_batched(grid, policy="oracle", episodes: int = 1,
     "cells" axis -- and, on a ``("cells", "model")`` mesh
     (``use_mesh(model=M)``), with M-way per-cell tensor parallelism -- and
     still returns logical-B outputs that match the single-device run to
-    1e-5.
+    1e-5.  ``oracle_backend`` picks the Oracle's sweep (see
+    ``ScenarioGrid.objective_tables``).
     """
-    rollout = grid.make_rollout(policy, steps)
+    rollout = grid.make_rollout(policy, steps, oracle_backend=oracle_backend)
     key = jax.random.PRNGKey(seed)
     agg: dict[str, list] = {}
     results = None

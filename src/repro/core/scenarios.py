@@ -523,8 +523,9 @@ class ScenarioGrid:
 
         backend:
           * ``"pallas"`` -- one ``partition_sweep`` kernel launch over the
-            flattened (B*N, C) grid (requires uniform kernel scalars across
-            cells; ``interpret=True`` off-TPU).
+            flattened (B*N, C) grid, one per cell shard on a sharded grid
+            (requires uniform kernel scalars across cells;
+            ``interpret=True`` off-TPU).
           * ``"ref"``    -- ``kernels.ref`` checked fallback (vmapped).
           * ``"lax"``    -- vmapped ``sweep.objective_table_p``.
           * ``"auto"``   -- pallas on TPU when eligible, else lax.
@@ -538,17 +539,23 @@ class ScenarioGrid:
         if self.sweep_scalars is None:
             raise ValueError(
                 "kernel scalars differ across cells; use backend='lax'")
-        args = (p.macs, p.param_bytes, p.act_bytes, p.psi, p.L,
-                states.lam, states.gain, states.queues.energy,
-                states.queues.memory, self.sweep_scalars)
+        tables = (p.macs, p.param_bytes, p.act_bytes, p.psi, p.L,
+                  states.lam, states.gain, states.queues.energy,
+                  states.queues.memory)
         if backend == "ref":
             from ..kernels.ref import partition_sweep_batched_ref
-            return partition_sweep_batched_ref(*args)
+            return partition_sweep_batched_ref(*tables, self.sweep_scalars)
         if backend == "pallas":
             from ..kernels.ops import partition_sweep_batched
             if interpret is None:
                 interpret = jax.default_backend() != "tpu"
-            return partition_sweep_batched(*args, interpret=interpret)
+
+            def sweep_fn(*tables):
+                return partition_sweep_batched(*tables, self.sweep_scalars,
+                                               interpret=interpret)
+            if self.gridshard is not None and p is self._run_params:
+                sweep_fn = gridshard.per_shard(sweep_fn, self.gridshard)
+            return sweep_fn(*tables)
         raise ValueError(f"unknown backend {backend!r}")
 
     def oracle_cuts(self, states: MecState, *, backend: str = "auto",

@@ -33,9 +33,9 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, acc_ref, m_ref, l_ref, *,
     q = q_ref[0, 0].astype(jnp.float32)           # (G, hd)
     k = k_ref[0, 0].astype(jnp.float32)           # (Kb, hd)
     v = v_ref[0, 0].astype(jnp.float32)
-    valid = mask_ref[0]                           # (Kb,)
+    valid = mask_ref[0]                           # (1, Kb)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (G, Kb)
-    s = jnp.where(valid[None, :], s, _NEG)
+    s = jnp.where(valid, s, _NEG)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -90,7 +90,9 @@ def decode_attention_pallas(q, k, v, *, valid_mask, k_block: int = 512,
                          lambda b_, k_, ik: (b_, k_, ik, 0)),
             pl.BlockSpec((1, 1, k_block, hd),
                          lambda b_, k_, ik: (b_, k_, ik, 0)),
-            pl.BlockSpec((1, k_block), lambda b_, k_, ik: (b_, ik)),
+            # (B, 1, S) layout: the block's last two dims (1, k_block)
+            # satisfy the TPU tiling rule at any slot count B
+            pl.BlockSpec((1, 1, k_block), lambda b_, k_, ik: (b_, 0, ik)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, hd), lambda b_, k_, ik: (b_, k_, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
@@ -100,5 +102,5 @@ def decode_attention_pallas(q, k, v, *, valid_mask, k_block: int = 512,
             pltpu.VMEM((g, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(qr, kr, vr, valid_mask)
+    )(qr, kr, vr, valid_mask[:, None, :])
     return out.reshape(b, 1, h, hd)

@@ -45,6 +45,8 @@ def _kernel(*refs, kind: str, window: int, q_block: int, k_block: int,
         pad_ref = None
     iq = pl.program_id(2)
     ik = pl.program_id(3)
+    # the whole (B,) pad vector sits in SMEM; this row's count is a scalar
+    pad_b = pad_ref[pl.program_id(0)] if pad_ref is not None else None
 
     @pl.when(ik == 0)
     def _init():
@@ -65,7 +67,7 @@ def _kernel(*refs, kind: str, window: int, q_block: int, k_block: int,
         relevant = tile_live
     if pad_ref is not None:
         # key tile entirely inside this row's left pad: skip it outright
-        relevant = relevant & (k_start + k_block > pad_ref[0])
+        relevant = relevant & (k_start + k_block > pad_b)
 
     @pl.when(relevant)
     def _compute():
@@ -88,7 +90,7 @@ def _kernel(*refs, kind: str, window: int, q_block: int, k_block: int,
             bound = k_pos < k_len    # last live tile has phantom columns
             mask = bound if mask is None else mask & bound
         if pad_ref is not None:
-            valid = k_pos >= pad_ref[0]
+            valid = k_pos >= pad_b
             mask = valid if mask is None else mask & valid
         if mask is not None:
             s = jnp.where(mask, s, _NEG)
@@ -154,7 +156,7 @@ def flash_attention_pallas(q, k, v, *, kind: str = "causal", window: int = 0,
     operands = [qr, kr, vr]
     if pad is not None:
         operands.append(jnp.asarray(pad, jnp.int32))
-        in_specs.append(pl.BlockSpec((1,), lambda b_, k_, iq, ik: (b_,)))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
 
     kernel = functools.partial(
         _kernel, kind=kind, window=window, q_block=q_block, k_block=k_block,

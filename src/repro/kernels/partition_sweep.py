@@ -53,12 +53,15 @@ def _kernel(macs_ref, params_ref, acts_ref, psi_ref, l_ref, lam_ref,
     qe = qe_ref[...].astype(jnp.float32)
     qm = qm_ref[...].astype(jnp.float32)
 
-    cols = jax.lax.broadcasted_iota(jnp.float32, macs.shape, 1)
+    # Mosaic builds integer iotas only: make them int32, then cast
+    iota = lambda shape, dim: jax.lax.broadcasted_iota(
+        jnp.int32, shape, dim).astype(jnp.float32)
+    cols = iota(macs.shape, 1)
     in_range = cols <= l_n                          # valid cuts per UE
 
     # -- prefix sums via upper-triangular ones matmul (MXU) ------------------
-    rows_t = jax.lax.broadcasted_iota(jnp.float32, (c, c), 0)
-    cols_t = jax.lax.broadcasted_iota(jnp.float32, (c, c), 1)
+    rows_t = iota((c, c), 0)
+    cols_t = iota((c, c), 1)
     tri = (rows_t <= cols_t).astype(jnp.float32)    # T[j,c] = 1 iff j <= c
     prefix_macs = jax.lax.dot_general(macs, tri, (((1,), (0,)), ((), ())))
     prefix_params = jax.lax.dot_general(params, tri, (((1,), (0,)), ((), ())))

@@ -30,6 +30,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _cumsum0(t):
+    """Inclusive cumsum down axis 0 by log2(Q) roll-and-add doubling passes
+    (Mosaic has no cumsum primitive)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, t.shape, 0)
+    shift = 1
+    while shift < t.shape[0]:
+        t = t + jnp.where(rows >= shift, jnp.roll(t, shift, axis=0), 0)
+        shift *= 2
+    return t
+
+
 def _kernel(*refs, nchunks: int, chunk: int, has_reset: bool):
     if has_reset:
         (x_ref, dt_ref, alog_ref, b_ref, c_ref, dskip_ref, reset_ref,
@@ -48,11 +59,13 @@ def _kernel(*refs, nchunks: int, chunk: int, has_reset: bool):
     dt = dt_ref[0, 0].astype(jnp.float32)       # (Q, 1) -> column
     bmat = b_ref[0, 0].astype(jnp.float32)      # (Q, N)
     cmat = c_ref[0, 0].astype(jnp.float32)      # (Q, N)
-    a = -jnp.exp(alog_ref[0].astype(jnp.float32))   # scalar A_h
-    d = dskip_ref[0].astype(jnp.float32)
+    # per-head scalars from the whole (H,) vectors held in SMEM
+    ih = pl.program_id(1)
+    a = -jnp.exp(jnp.full((1, 1), alog_ref[ih], jnp.float32))   # A_h
+    d = dskip_ref[ih]
 
     la = a * dt                                  # (Q,1) log decay per step
-    cum = jnp.cumsum(la, axis=0)                 # (Q,1)
+    cum = _cumsum0(la)                           # (Q,1)
     total = cum[chunk - 1:chunk, :]              # (1,1)
 
     # intra-chunk triangular term (log-domain masked decay)
@@ -64,7 +77,7 @@ def _kernel(*refs, nchunks: int, chunk: int, has_reset: bool):
     if reset_ref is not None:
         # seg[q] = #resets at in-chunk positions <= q; decay(q, r) survives
         # only when no reset lies in (r, q] -- i.e. seg_q == seg_r.
-        seg = jnp.cumsum(reset_ref[0].astype(jnp.int32), axis=0)   # (Q,1)
+        seg = _cumsum0(reset_ref[0].astype(jnp.int32))   # (Q,1)
         keep = keep & (seg == seg.T)
     ldecay = jnp.where(keep, ldecay, -jnp.inf)
     w = scores * jnp.exp(ldecay)                 # (Q,Q)
@@ -91,6 +104,10 @@ def _kernel(*refs, nchunks: int, chunk: int, has_reset: bool):
         carry = jnp.where(seg[chunk - 1:chunk, :] == 0, carry, 0.0)
     contrib = jax.lax.dot_general(bmat * (decay_to_end * dt), x,
                                   (((0,), (0,)), ((), ())))   # (N,P)
+    # (1,1) -> (N,1) through an add, then lane-broadcast: Mosaic broadcasts
+    # along sublanes or lanes in one op, never both (a plain broadcast_to
+    # chain folds back into one op)
+    carry = jnp.zeros((m_ref.shape[0], 1), jnp.float32) + carry
     m_ref[...] = m_ref[...] * carry + contrib
 
     @pl.when(ic == nchunks - 1)
@@ -119,12 +136,13 @@ def ssd_scan_pallas(x, dt, a_log, b, c, d_skip, *, chunk: int,
     in_specs = [
         pl.BlockSpec((1, 1, chunk, p), lambda b_, h_, c_: (b_, h_, c_, 0)),
         pl.BlockSpec((1, 1, chunk, 1), lambda b_, h_, c_: (b_, h_, c_, 0)),
-        pl.BlockSpec((1,), lambda b_, h_, c_: (h_,)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((1, 1, chunk, n), lambda b_, h_, c_: (b_, h_, c_, 0)),
         pl.BlockSpec((1, 1, chunk, n), lambda b_, h_, c_: (b_, h_, c_, 0)),
-        pl.BlockSpec((1,), lambda b_, h_, c_: (h_,)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
-    operands = [xr, dtr, a_log, br, cr, d_skip]
+    f32 = lambda t: t.astype(jnp.float32)
+    operands = [xr, dtr, f32(a_log), br, cr, f32(d_skip)]
     if reset is not None:
         operands.append(reset.astype(jnp.float32)[:, :, None])   # (B,S,1)
         in_specs.append(pl.BlockSpec((1, chunk, 1),

@@ -6,18 +6,42 @@ jax device state (device count locks on first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, devices=None):
+    """The one mesh constructor: every axis is Auto.  ``jax.make_mesh``
+    defaults to Explicit axes, which ``shardctx.constrain``'s
+    ``with_sharding_constraint`` and the grid's ``NamedSharding`` placement
+    refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 = 256 chips/pod; multi-pod adds a leading 2-pod axis (512)."""
+    """16x16 = 256 chips/pod; multi-pod adds a leading 2-pod axis (512).
+    Only the compile-only dry run (launch/dryrun.py) builds it."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh for CPU smoke runs of the same launch code."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
+
+
+def make_live_mesh(model: int | None = None):
+    """``(data=1, model=M)`` over the live devices, for the serve and train
+    launchers: M defaults to the device count, so one chip runs unsharded
+    and four shard heads/FFN 4 ways."""
+    n = len(jax.devices())
+    model = n if model is None else int(model)
+    if not 1 <= model <= n:
+        raise ValueError(f"model axis {model} needs 1..{n} live devices")
+    return auto_mesh((1, model), ("data", "model"),
+                     devices=jax.devices()[:model])
 
 
 def data_axes(mesh) -> tuple:
@@ -60,8 +84,9 @@ def make_cells_mesh(n_devices: int | None = None, *, model: int = 1):
             f"model-axis size from the divisors of {n} "
             f"(e.g. {[d for d in (1, 2, 4, 8) if n % d == 0]})")
     if model > 1:
-        return jax.make_mesh((n // model, model), ("cells", "model"))
-    return jax.make_mesh((n,), ("cells",))
+        return auto_mesh((n // model, model), ("cells", "model"),
+                         devices=jax.devices()[:n])
+    return auto_mesh((n,), ("cells",), devices=jax.devices()[:n])
 
 
 def elastic_mesh(target_model: int = 16):
@@ -72,4 +97,4 @@ def elastic_mesh(target_model: int = 16):
     model = min(target_model, n)
     while n % model:
         model -= 1
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return auto_mesh((n // model, model), ("data", "model"))

@@ -1,8 +1,9 @@
 """Serving launcher: the ES-side engine under the LyMDO controller.
 
-``--smoke`` serves the reduced config on CPU with synthetic requests and
-prints per-request latency; on hardware the same code path runs the full
-config under the production mesh.
+``--smoke`` serves the reduced config on a 1x1 host mesh with synthetic
+requests and prints per-request latency; without it the full config runs
+on a ``(data=1, model=M)`` mesh of the live devices (M = ``--model``,
+default every device: one chip serves unsharded).
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b --smoke \
       --requests 6
@@ -18,7 +19,8 @@ import numpy as np
 from ..configs.base import get_config, reduced
 from ..models import transformer
 from ..serving.engine import Request, ServingEngine
-from .mesh import make_host_mesh, make_production_mesh
+from .compile_cache import enable_compile_cache
+from .mesh import make_host_mesh, make_live_mesh
 
 
 def main():
@@ -29,25 +31,27 @@ def main():
     ap.add_argument("--slots", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=8)
-    ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--model", type=int, default=None,
+                    help="model-axis size (default: every live device)")
     ap.add_argument("--sync-batching", action="store_true",
                     help="use the synchronized-batch compat engine instead "
                          "of continuous batching (A/B baseline)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduced(cfg)
         mesh = make_host_mesh()
     else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+        mesh = make_live_mesh(args.model)
     if cfg.enc_layers:
         raise SystemExit("enc-dec serving needs src embeddings; use "
                          "examples/serve_partitioned.py patterns")
 
     params = transformer.init_params(jax.random.PRNGKey(0), cfg)
     print(f"[serve] {cfg.name}: {transformer.param_count(params)/1e6:.2f}M "
-          f"params, {args.slots} slots")
+          f"params, {args.slots} slots, mesh {dict(mesh.shape)}")
 
     from ..shardctx import activation_sharding
     with mesh, activation_sharding(mesh):

@@ -1,9 +1,10 @@
 """LM training launcher.
 
-On real hardware this runs under the production mesh with the recommended
-sharding policy; on CPU (this container) pass ``--smoke`` to train the
-reduced config of the same family end-to-end with checkpointing, straggler
-monitoring, and restart-from-latest — the full driver path.
+Without ``--smoke`` this runs on a ``(data=1, model=M)`` mesh of the live
+devices with the recommended sharding policy; ``--smoke`` trains the
+reduced config of the same family on a 1x1 host mesh end-to-end with
+checkpointing, straggler monitoring, and restart-from-latest — the full
+training path.
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b --smoke \
       --steps 50 --ckpt-dir /tmp/run1
@@ -21,7 +22,7 @@ from ..models import transformer
 from ..models.steps import default_microbatches, make_train_step
 from ..runtime.checkpoint import CheckpointManager
 from ..runtime.resilience import StragglerMonitor
-from .mesh import make_host_mesh, make_production_mesh
+from .mesh import make_host_mesh, make_live_mesh
 from . import sharding
 
 
@@ -36,7 +37,8 @@ def main():
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
-    ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--model", type=int, default=None,
+                    help="model-axis size (default: every live device)")
     args = ap.parse_args()
 
     cfg = get_config(args.arch)
@@ -44,7 +46,7 @@ def main():
         cfg = reduced(cfg)
         mesh = make_host_mesh()
     else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+        mesh = make_live_mesh(args.model)
     opts = sharding.recommended_options(cfg, "train")
 
     params = transformer.init_params(jax.random.PRNGKey(0), cfg)

@@ -78,10 +78,9 @@ def make_dp_train_step(mesh, loss_fn, opt_update, axis: str = "data",
         params, opt_state = opt_update(grads, opt_state, params)
         return params, opt_state, residual, jax.lax.pmean(loss, axis)
 
-    from jax.experimental.shard_map import shard_map
     rep = P()
-    return shard_map(
+    return jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(rep, rep, rep, P(axis)),
         out_specs=(rep, rep, rep, rep),
-        check_rep=False)
+        check_vma=False)

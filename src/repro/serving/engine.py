@@ -216,12 +216,15 @@ class ServingEngine:
             logits, cache = out
             return jnp.argmax(logits, -1).astype(jnp.int32), cache
 
+        # The weights enter every model program as its first ARGUMENT:
+        # weights a jitted function closes over are baked into the program
+        # as constants, one device copy per compiled program.
         self._prefill = _jit(
-            lambda batch, pad: greedy(transformer.prefill(
+            lambda params, batch, pad: greedy(transformer.prefill(
                 params, cfg, batch, s_max=s_max, pad=pad)))
         if sync_batching:
             self._decode = _jit(
-                lambda cache, toks: greedy(transformer.decode_step(
+                lambda params, cache, toks: greedy(transformer.decode_step(
                     params, cfg, cache, toks)))
             return
 
@@ -243,7 +246,7 @@ class ServingEngine:
         self.owned: list[list[int]] = [[] for _ in range(slots)]
         self._admit_seq = np.full(slots, -1, np.int64)      # admission order
         self._admit_counter = 0
-        # The per-tick state updates DONATE their input pool (argnum 0):
+        # The per-tick state updates DONATE their input pool:
         # the engine always rebinds self._pool_state to the result, and
         # without donation every tick/commit briefly holds TWO full KV
         # pools live -- the exact peak-memory hazard
@@ -253,10 +256,10 @@ class ServingEngine:
                 state, solo, pad, slot, ids, block_size=kv_block),
             donate=0)
         self._decode_paged = _jit(
-            lambda state, toks, table, lens: greedy(
+            lambda params, state, toks, table, lens: greedy(
                 transformer.decode_step_paged(params, cfg, state, toks,
                                               table, lens)),
-            donate=0)
+            donate=1)
         # -- chunked-prefill stream state: at most ONE request mid-prefill
         # (see _start_stream / _advance_stream).  Both chunk programs take
         # traced scalars and a full table-width id row, so each compiles
@@ -269,10 +272,10 @@ class ServingEngine:
         self._stream_ids = None              # device (table_width,) block row
         if self.prefill_chunk is not None:
             self._chunk_step = _jit(
-                lambda cache, toks, start, n_valid: greedy(
+                lambda params, cache, toks, start, n_valid: greedy(
                     transformer.prefill_chunk(params, cfg, cache, toks,
                                               start, n_valid)),
-                donate=0)
+                donate=1)
             self._commit_chunk = _jit(
                 lambda state, solo, start, n_new, slot, ids:
                     kvpool.commit_chunk(state, solo, start, n_new, slot,
@@ -362,7 +365,7 @@ class ServingEngine:
         self._prefill_shapes.add((1, width, pad_arg is not None))
         t0 = self.obs.now() if self.obs is not None else 0.0
         tok, cache = self._prefill(
-            {"tokens": jnp.asarray(toks, jnp.int32)}, pad_arg)
+            self.params, {"tokens": jnp.asarray(toks, jnp.int32)}, pad_arg)
         # admission's one sanctioned sync: a single int32 per admitted request
         nxt = int(np.asarray(tok)[0])    # reprolint: ignore[host-sync]
         if self.obs is not None:         # host state only: span + compile gauge
@@ -471,7 +474,8 @@ class ServingEngine:
         toks = np.asarray(req.prompt, np.int32)[None, :c]
         self._prefill_shapes.add((1, c, False))
         t0 = self.obs.now() if self.obs is not None else 0.0
-        _, cache = self._prefill({"tokens": jnp.asarray(toks)}, None)
+        _, cache = self._prefill(self.params, {"tokens": jnp.asarray(toks)},
+                                 None)
         cache = {"units": cache["units"], "tail": cache["tail"]}
         if self.obs is not None:
             self.obs.on_prefill(self, t0, batch=1, width=c, chunked=True)
@@ -516,8 +520,9 @@ class ServingEngine:
         chunk = np.zeros((1, c), np.int32)
         chunk[0, :n_valid] = req.prompt[start:start + n_valid]
         t0 = self.obs.now() if self.obs is not None else 0.0
-        tok, cache = self._chunk_step(self._stream_cache, jnp.asarray(chunk),
-                                      jnp.int32(start), jnp.int32(n_valid))
+        tok, cache = self._chunk_step(self.params, self._stream_cache,
+                                      jnp.asarray(chunk), jnp.int32(start),
+                                      jnp.int32(n_valid))
         self._pool_state = self._commit_chunk(
             self._pool_state, cache, jnp.int32(start), jnp.int32(n_valid),
             jnp.int32(slot), self._stream_ids)
@@ -634,7 +639,7 @@ class ServingEngine:
             table = table.copy()
             table[self._stream_slot] = 0
         toks, self._pool_state = self._decode_paged(
-            self._pool_state, jnp.asarray(self.last_tokens),
+            self.params, self._pool_state, jnp.asarray(self.last_tokens),
             jnp.asarray(table), jnp.asarray(self.seq_lens))
         self.decode_steps += 1
         # the tick's one sanctioned sync: (slots,) int32 token ids
@@ -696,7 +701,7 @@ class ServingEngine:
         self._prefill_shapes.add(toks.shape + (pad_arg is not None,))
         t0 = self.obs.now() if self.obs is not None else 0.0
         tok_ids, cache = self._prefill(
-            {"tokens": jnp.asarray(toks, jnp.int32)}, pad_arg)
+            self.params, {"tokens": jnp.asarray(toks, jnp.int32)}, pad_arg)
         self.cache = cache
         # admission's one sanctioned sync (batch x int32)
         nxt = np.asarray(tok_ids)        # reprolint: ignore[host-sync]
@@ -734,7 +739,7 @@ class ServingEngine:
             return bool(self.queue)
         live = sum(1 for r in self.active if r is not None)
         t0 = obs.now() if sampled else 0.0
-        toks, self.cache = self._decode(self.cache,
+        toks, self.cache = self._decode(self.params, self.cache,
                                         jnp.asarray(self._last, jnp.int32))
         self.decode_steps += 1
         # the tick's one sanctioned sync: (slots,) int32 token ids

@@ -170,10 +170,13 @@ def test_objective_tables_on_padded_states():
 # Sharded == unsharded parity (the 1e-5 contract)
 # ---------------------------------------------------------------------------
 
-def _assert_parity(b: int, pad_to, policy: str, steps: int = 12):
+def _assert_parity(b: int, pad_to, policy: str, steps: int = 12,
+                   oracle_backend: str = "auto"):
     g_plain, g_shard = _grid_pair(b, pad_to=pad_to)
-    st_p, res_p, sum_p = g_plain.rollout(policy, steps=steps, seed=3)
-    st_s, res_s, sum_s = g_shard.rollout(policy, steps=steps, seed=3)
+    st_p, res_p, sum_p = g_plain.rollout(policy, steps=steps, seed=3,
+                                         oracle_backend=oracle_backend)
+    st_s, res_s, sum_s = g_shard.rollout(policy, steps=steps, seed=3,
+                                         oracle_backend=oracle_backend)
     assert set(sum_p) == set(sum_s)
     for name in sum_p:
         assert np.asarray(sum_s[name]).shape == (b,)
@@ -199,6 +202,15 @@ def test_sharded_parity_even_b():
 def test_sharded_parity_uneven_b_exercises_padding():
     b = 6                                 # uneven on 8 (and forced elsewhere)
     g = _assert_parity(b, _forced_pad_to(b), "oracle")
+    assert g.gridshard.pad > 0
+
+
+def test_sharded_parity_pallas_sweep():
+    """The Oracle's Pallas sweep runs once per cell shard (GSPMD cannot
+    partition a Mosaic kernel): still equal to the unsharded rollout."""
+    b = 5
+    g = _assert_parity(b, _forced_pad_to(b), "oracle", steps=6,
+                       oracle_backend="pallas")
     assert g.gridshard.pad > 0
 
 

@@ -8,6 +8,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import get_config
 from repro.launch import specs
 from repro.launch.dryrun import _result_bytes, collective_bytes
+from repro.launch.mesh import make_host_mesh
 from repro.launch.sharding import (ShardingOptions, batch_shardings,
                                    cache_shardings, param_spec)
 
@@ -127,7 +128,7 @@ def test_cell_supported_skip_rules():
 # ---------------------------------------------------------------------------
 
 def _real_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_host_mesh()
 
 
 def test_batch_shardings_scalar_leaf_replicated():
@@ -160,7 +161,7 @@ def test_cache_shardings_batch_position_rules():
     }
     sh = cache_shardings(mesh, cfg, cache, batch=B)
     # KV tensors keep the dedicated (lead, batch, seq, kv, hd) rule
-    assert sh["units"]["slot0"]["k"].spec[1] == ("data",)
+    assert P(sh["units"]["slot0"]["k"].spec[1]) == P(("data",))
     # stacked recurrent state: batch at dim 1
     assert sh["units"]["slot0"]["state"].spec == P(None, ("data",), None)
     # tail leaf: batch leading

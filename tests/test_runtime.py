@@ -56,7 +56,8 @@ def test_checkpoint_atomicity(tmp_path, tree):
 def test_checkpoint_restore_with_sharding(tmp_path, tree):
     mgr = CheckpointManager(str(tmp_path), async_save=False)
     mgr.save(3, tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), tree)
     restored, _ = mgr.restore(tree, shardings=sh)
@@ -136,17 +137,17 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.runtime.compression import make_grad_sync
-from jax.experimental.shard_map import shard_map
+from repro.launch.mesh import auto_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = auto_mesh((8,), ("data",))
 g = {"w": jnp.linspace(-1.0, 1.0, 64).reshape(8, 8)}
 r = {"w": jnp.zeros((8, 8))}
 
 for mode in ("none", "bf16", "int8"):
     sync = make_grad_sync(mesh, "data", mode=mode, error_feedback=True)
-    f = shard_map(lambda gg, rr: sync(gg, rr), mesh=mesh,
-                  in_specs=(P("data"), P("data")), out_specs=(P("data"), P("data")),
-                  check_rep=False)
+    f = jax.shard_map(lambda gg, rr: sync(gg, rr), mesh=mesh,
+                      in_specs=(P("data"), P("data")),
+                      out_specs=(P("data"), P("data")), check_vma=False)
     out, res = f(g, r)
     # psum over identical shards at different rows -> compare vs numpy mean
     got = np.asarray(out["w"])
@@ -158,9 +159,9 @@ for mode in ("none", "bf16", "int8"):
 
 # error feedback drives the MEAN quantization bias to zero over steps
 sync = make_grad_sync(mesh, "data", mode="int8", error_feedback=True)
-f = shard_map(lambda gg, rr: sync(gg, rr), mesh=mesh,
-              in_specs=(P("data"), P("data")), out_specs=(P("data"), P("data")),
-              check_rep=False)
+f = jax.shard_map(lambda gg, rr: sync(gg, rr), mesh=mesh,
+                  in_specs=(P("data"), P("data")),
+                  out_specs=(P("data"), P("data")), check_vma=False)
 accum = np.zeros((8, 8)); res = {"w": jnp.zeros((8, 8))}
 for i in range(50):
     out, res = f(g, res)
