@@ -227,8 +227,26 @@ def chunk_self_attention(p, cfg, x, cache: KVCache, start, positions):
     return out @ p["wo"], KVCache(k=k, v=v)
 
 
+def paged_write_target(block_table, seq_lens, block_size: int):
+    """Where each row's new K/V lands in the pool: block
+    ``block_table[i, seq_lens[i] // block_size]`` at offset
+    ``seq_lens[i] % block_size``.  Returns ``(blk, off)``, both (B,)."""
+    rows = jnp.arange(block_table.shape[0])
+    return block_table[rows, seq_lens // block_size], seq_lens % block_size
+
+
+def write_paged(pool: KVCache, new: KVCache, blk, off) -> KVCache:
+    """Scatter new K/V rows into a pool at ``(blk, off)`` (see
+    :func:`paged_write_target`): ``pool`` is ``(n_blocks, bs, KV, hd)`` with
+    ``new`` ``(B, KV, hd)``, or stacked ``(U, n_blocks, bs, KV, hd)`` with
+    ``new`` ``(U, B, KV, hd)`` -- every unit's rows in one scatter.  On a
+    donated pool the scatter writes in place."""
+    return KVCache(k=pool.k.at[..., blk, off, :, :].set(new.k),
+                   v=pool.v.at[..., blk, off, :, :].set(new.v))
+
+
 def decode_self_attention_paged(p, cfg, x, cache, *, kind: str,
-                                block_table, seq_lens):
+                                block_table, seq_lens, layer=None):
     """Single-token decode against per-slot caches (continuous batching).
 
     Unlike :func:`decode_self_attention` there is no shared write frontier
@@ -236,18 +254,25 @@ def decode_self_attention_paged(p, cfg, x, cache, *, kind: str,
     ``seq_lens[i]`` (the position being written) and the caches are
     pad-free (see ``serving.kvpool.commit_prefill``).
 
-    * ``kind == "g"``: ``cache`` is a pool :class:`KVCache`
-      ``(n_blocks, block_size, KV, hd)``; ``block_table`` (B, M) maps each
-      row's logical block index to a pool block.  The new K/V scatters into
-      block ``block_table[i, seq_lens[i] // bs]`` at offset
-      ``seq_lens[i] % bs``; attention gathers the row's blocks back into a
-      contiguous ``(B, M*bs)`` view with positions ``> seq_lens[i]`` masked.
-      Idle rows (``seq_lens == 0``, table all zeros) write to the reserved
-      dummy block 0 -- harmless garbage nobody gathers as valid beyond
-      position 0, and their outputs are discarded by the engine.
+    * ``kind == "g"``: ``cache`` is a pool :class:`KVCache`, READ-ONLY here:
+      ``(n_blocks, block_size, KV, hd)``, or the stacked
+      ``(U, n_blocks, block_size, KV, hd)`` of a layer scan with ``layer``
+      the scan's unit index.  ``block_table`` (B, M) maps each row's logical
+      block index to a pool block.  Each row gathers its blocks into a
+      contiguous ``(B, M*bs)`` view straight from the pool, puts its new K/V
+      into that view at ``seq_lens[i]``, and attends with positions
+      ``> seq_lens[i]`` masked.  Returns the new rows as a ``(B, KV, hd)``
+      :class:`KVCache` in place of a pool: the caller writes them once
+      with :func:`write_paged` at :func:`paged_write_target` (block
+      ``block_table[i, seq_lens[i] // bs]``, offset ``seq_lens[i] % bs``),
+      so a donated pool is updated in place and never copied.  Idle rows
+      (``seq_lens == 0``, table all zeros) write to the reserved dummy
+      block 0 -- harmless garbage nobody gathers as valid beyond position
+      0, and their outputs are discarded by the engine.
     * ``kind == "l"``: ``cache`` is a per-slot :class:`RingCache`; row
       ``i`` writes its ring slot ``seq_lens[i] % window`` (semantic
-      positions -- commit re-slots prefill entries).
+      positions -- commit re-slots prefill entries) and the new ring is
+      returned.
     """
     from ..kernels import ops
     q = _project_q(p, cfg, x)               # (B, 1, H, hd)
@@ -256,33 +281,31 @@ def decode_self_attention_paged(p, cfg, x, cache, *, kind: str,
         pvec = seq_lens[:, None]                    # (B, 1) per-row position
         q = rope(q, pvec, cfg.rope_theta)
         k_new = rope(k_new, pvec, cfg.rope_theta)
-    k_new = k_new.astype(cache.k.dtype)
-    v_new = v_new.astype(cache.v.dtype)
+    k_new = k_new.astype(cache.k.dtype)[:, 0]
+    v_new = v_new.astype(cache.v.dtype)[:, 0]
     b = x.shape[0]
     rows = jnp.arange(b)
 
     if kind == "l":
         w = cache.k.shape[1]
         slot = seq_lens % w                                  # (B,)
-        k = cache.k.at[rows, slot].set(k_new[:, 0])
-        v = cache.v.at[rows, slot].set(v_new[:, 0])
+        k = cache.k.at[rows, slot].set(k_new)
+        v = cache.v.at[rows, slot].set(v_new)
         pos_buf = cache.pos.at[rows, slot].set(seq_lens)
         valid = (pos_buf >= 0) & (pos_buf >= (seq_lens - w + 1)[:, None])
         out = ops.decode_attention(q, k, v, valid_mask=valid)
         new_cache = RingCache(k=k, v=v, pos=pos_buf)
     else:
-        bs = cache.k.shape[1]                                # block_size
+        bs, kvh, hd = cache.k.shape[-3:]
         m = block_table.shape[1]
-        blk = block_table[rows, seq_lens // bs]              # (B,) pool ids
-        off = seq_lens % bs
-        k = cache.k.at[blk, off].set(k_new[:, 0])
-        v = cache.v.at[blk, off].set(v_new[:, 0])
-        kvh, hd = k.shape[-2:]
-        k_rows = k[block_table].reshape(b, m * bs, kvh, hd)
-        v_rows = v[block_table].reshape(b, m * bs, kvh, hd)
+        idx = block_table if layer is None else (layer, block_table)
+        k_rows = cache.k[idx].reshape(b, m * bs, kvh, hd)
+        v_rows = cache.v[idx].reshape(b, m * bs, kvh, hd)
+        k_rows = k_rows.at[rows, seq_lens].set(k_new)
+        v_rows = v_rows.at[rows, seq_lens].set(v_new)
         valid = jnp.arange(m * bs)[None, :] <= seq_lens[:, None]
         out = ops.decode_attention(q, k_rows, v_rows, valid_mask=valid)
-        new_cache = KVCache(k=k, v=v)
+        new_cache = KVCache(k=k_new, v=v_new)
     out = out.reshape(*x.shape[:-1], -1)
     return out @ p["wo"], new_cache
 

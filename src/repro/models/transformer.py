@@ -521,10 +521,14 @@ def prefill_chunk(params, cfg, caches, tokens, start, n_valid):
         return logits, {"units": new_unit_caches, "tail": new_tail}
 
 
-def _layer_decode_paged(p, cfg, kind, x, cache, block_table, seq_lens):
+def _layer_decode_paged(p, cfg, kind, x, cache, block_table, seq_lens,
+                        layer=None):
     """Single-token layer step with per-slot cache positions.  Recurrent
     kinds keep per-row O(1) state, so they are position-free and reuse the
     synchronized step; attention kinds go through the paged/per-slot path.
+    A pool (:class:`attn.KVCache`) is read-only here, at unit ``layer`` of
+    a stacked pool: the step returns the rows' new K/V in its place (see
+    :func:`attn.decode_self_attention_paged`).
     """
     if kind in ("s", "r"):
         return _layer_decode(p, cfg, kind, x, cache, None)
@@ -532,7 +536,7 @@ def _layer_decode_paged(p, cfg, kind, x, cache, block_table, seq_lens):
     normed = rms_norm(x, p["norm1"])
     out, cache = attn.decode_self_attention_paged(
         p["attn"], cfg, normed, cache, kind=akind,
-        block_table=block_table, seq_lens=seq_lens)
+        block_table=block_table, seq_lens=seq_lens, layer=layer)
     x = x + out
     if kind == "m":
         y, _ = ffn_mod.apply_moe(p["moe"], cfg, rms_norm(x, p["norm2"]))
@@ -551,6 +555,15 @@ def decode_step_paged(params, cfg, caches, tokens, block_table, seq_lens):
     (logits (B, V), caches).  Cross-attention kinds are not servable here
     (see ``kvpool._check_pattern``).
 
+    The KV pools (:class:`attn.KVCache` leaves) are read-only inside the
+    layer scan: each pool layer gathers its rows' blocks straight from the
+    stacked pool and emits its rows' new K/V as the scan's output, and
+    after the scan one scatter per tensor writes every layer's rows into
+    the pool (:func:`attn.write_paged`).  The engine donates ``caches``, so
+    that write is in place; carrying the pools through the scan instead
+    would copy them whole every step.  Ring and recurrent state is per
+    slot and small, and rides the scan as before.
+
     Named ``repro.decode_paged`` for profiler dumps (the engine runs it as
     the ``decode_paged`` program, between its ``engine.dispatch`` and
     ``engine.sync`` spans)."""
@@ -558,26 +571,41 @@ def decode_step_paged(params, cfg, caches, tokens, block_table, seq_lens):
         seq_lens = jnp.asarray(seq_lens, jnp.int32)
         x = params["embed"][tokens][:, None, :].astype(
             dtype_of(cfg.compute_dtype))
+        units, tail = caches["units"], caches["tail"]
+        pools = {s: c for s, c in units.items()
+                 if isinstance(c, attn.KVCache)}
+        state = {s: c for s, c in units.items() if s not in pools}
+        kv = [c for c in (*pools.values(), *tail)
+              if isinstance(c, attn.KVCache)]
+        if kv:                      # every pool has the same block size
+            blk, off = attn.paged_write_target(block_table, seq_lens,
+                                               kv[0].k.shape[-3])
 
         def scan_body(x, inp):
-            unit_p, unit_c = inp
+            unit_p, unit_s, layer = inp
             new_c = {}
             for i, kind in enumerate(cfg.block_pattern):
-                x, c = _layer_decode_paged(unit_p[f"slot{i}"], cfg, kind, x,
-                                           unit_c[f"slot{i}"], block_table,
-                                           seq_lens)
-                new_c[f"slot{i}"] = c
+                s = f"slot{i}"
+                cache = pools[s] if s in pools else unit_s[s]
+                x, new_c[s] = _layer_decode_paged(
+                    unit_p[s], cfg, kind, x, cache, block_table, seq_lens,
+                    layer)
             return x, new_c
 
-        x, new_unit_caches = jax.lax.scan(
-            scan_body, x, (params["units"], caches["units"]))
+        n_units = jax.tree.leaves(params["units"])[0].shape[0]
+        x, new_c = jax.lax.scan(
+            scan_body, x, (params["units"], state, jnp.arange(n_units)))
+        new_units = {s: attn.write_paged(pools[s], c, blk, off)
+                     if s in pools else c for s, c in new_c.items()}
 
         new_tail = []
         for tp, kind, tc in zip(params.get("tail", []), cfg.tail_pattern,
-                                caches["tail"]):
+                                tail):
             x, c = _layer_decode_paged(tp, cfg, kind, x, tc, block_table,
                                        seq_lens)
+            if isinstance(tc, attn.KVCache):
+                c = attn.write_paged(tc, c, blk, off)
             new_tail.append(c)
 
         logits = _logits(params, cfg, x)[:, 0]
-        return logits, {"units": new_unit_caches, "tail": new_tail}
+        return logits, {"units": new_units, "tail": new_tail}

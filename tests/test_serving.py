@@ -615,6 +615,150 @@ def test_chunked_rejects_bad_chunk_and_moe(setup):
     assert eng.prefill_chunk is None
 
 
+# -- paged decode step -------------------------------------------------------
+
+def _paged_archs():
+    import dataclasses
+    return {
+        "attention": reduced(get_config("qwen3-0.6b"), n_layers=4),
+        "local-lg-tail": dataclasses.replace(
+            reduced(get_config("qwen3-0.6b"), n_layers=5),
+            name="local-lg-tail", block_pattern=("l", "g"),
+            tail_pattern=("g",), window=6),
+    }
+
+
+PAGED_ARCHS = _paged_archs()
+
+
+def _write_then_gather_step(params, cfg, caches, tokens, table, lens):
+    """Plain reference for one paged decode step: every "g" layer writes
+    each row's new K/V into its pool, each row gathers its blocks back from
+    a pool holding its own write, and attends; ring layers run the model's
+    own layer step.  Returns (logits, caches) like the model's step."""
+    from repro.kernels import ops
+    from repro.models import attention as attn
+    from repro.models import ffn as ffn_mod
+    from repro.models.common import dtype_of, rms_norm
+    rows = jnp.arange(tokens.shape[0])
+
+    def global_layer(p, x, pool):
+        bs = pool.k.shape[1]
+        h = rms_norm(x, p["norm1"])
+        q = attn._project_q(p["attn"], cfg, h)
+        k_new, v_new = attn._project_kv(p["attn"], cfg, h)
+        q = attn.rope(q, lens[:, None], cfg.rope_theta)
+        k_new = attn.rope(k_new, lens[:, None], cfg.rope_theta)
+        k_new = k_new[:, 0].astype(pool.k.dtype)
+        v_new = v_new[:, 0].astype(pool.v.dtype)
+        blk, off = table[rows, lens // bs], lens % bs
+
+        def view(t, b, o, new, tab):
+            return t.at[b, o].set(new)[tab].reshape(-1, *t.shape[2:])
+        k_rows = jax.vmap(view, (None, 0, 0, 0, 0))(pool.k, blk, off, k_new,
+                                                    table)
+        v_rows = jax.vmap(view, (None, 0, 0, 0, 0))(pool.v, blk, off, v_new,
+                                                    table)
+        valid = jnp.arange(k_rows.shape[1])[None, :] <= lens[:, None]
+        out = ops.decode_attention(q, k_rows, v_rows, valid_mask=valid)
+        x = x + out.reshape(*x.shape[:-1], -1) @ p["attn"]["wo"]
+        x = x + ffn_mod.apply_ffn(p["ffn"], cfg, rms_norm(x, p["norm2"]))
+        return x, attn.KVCache(k=pool.k.at[blk, off].set(k_new),
+                               v=pool.v.at[blk, off].set(v_new))
+
+    def layer(p, kind, x, cache):
+        if kind == "g":
+            return global_layer(p, x, cache)
+        return transformer._layer_decode_paged(p, cfg, kind, x, cache,
+                                               table, lens)
+
+    x = params["embed"][tokens][:, None, :].astype(
+        dtype_of(cfg.compute_dtype))
+    units = []
+    for u in range(cfg.n_units):
+        unit_p = jax.tree.map(lambda a: a[u], params["units"])
+        unit_c = jax.tree.map(lambda a: a[u], caches["units"])
+        new_c = {}
+        for i, kind in enumerate(cfg.block_pattern):
+            x, new_c[f"slot{i}"] = layer(unit_p[f"slot{i}"], kind, x,
+                                         unit_c[f"slot{i}"])
+        units.append(new_c)
+    tail = []
+    for tp, kind, tc in zip(params.get("tail", []), cfg.tail_pattern,
+                            caches["tail"]):
+        x, c = layer(tp, kind, x, tc)
+        tail.append(c)
+    logits = transformer._logits(params, cfg, x)[:, 0]
+    return logits, {"units": jax.tree.map(lambda *a: jnp.stack(a), *units),
+                    "tail": tail}
+
+
+@pytest.mark.parametrize("arch", list(PAGED_ARCHS))
+def test_paged_decode_matches_write_then_gather(arch):
+    """One paged decode step on a filled pool with live rows (one writing
+    mid-block, one at a block's first offset), an idle row and a streaming
+    row (the engine zeroes its table row and holds its length at 0): logits
+    and every cache leaf equal the write-then-gather reference bit for bit,
+    and each pool changes only at the rows' write targets
+    ``(unit, blk[i], off[i])``."""
+    from repro.models.attention import KVCache
+    from repro.serving import kvpool
+    cfg = PAGED_ARCHS[arch]
+    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    bs, m, n_blocks = 4, 8, 16
+    lens = np.array([13, 8, 0, 0], np.int32)
+    table = np.zeros((4, m), np.int32)
+    table[0, :4] = [3, 7, 1, 12]
+    table[1, :3] = [5, 2, 9]
+    state = kvpool.init_decode_state(cfg, params, 4, n_blocks, bs)
+    leaves, treedef = jax.tree.flatten(state)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    w = np.arange(cfg.window)
+    # ring slot j holds the newest position p < len with p % window == j
+    ring_pos = lens[:, None] - 1 - (lens[:, None] - 1 - w) % cfg.window
+    ring_pos = jnp.asarray(np.where(ring_pos >= 0, ring_pos, -1), jnp.int32)
+    state = jax.tree.unflatten(treedef, [
+        jax.random.normal(k, a.shape, a.dtype)
+        if jnp.issubdtype(a.dtype, jnp.floating)
+        else jnp.broadcast_to(ring_pos, a.shape)
+        for k, a in zip(keys, leaves)])
+    tokens = jnp.asarray([17, 4, 0, 99], jnp.int32)
+    table, lens = jnp.asarray(table), jnp.asarray(lens)
+
+    want, want_state = jax.jit(
+        lambda p, st: _write_then_gather_step(p, cfg, st, tokens, table,
+                                              lens))(params, state)
+    got, got_state = jax.jit(
+        lambda p, st: transformer.decode_step_paged(p, cfg, st, tokens,
+                                                    table, lens))(params,
+                                                                  state)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert jax.tree.structure(got_state) == jax.tree.structure(state)
+
+    # (blk, off): row 0 at table[0, 13 // 4] offset 1, row 1 at
+    # table[1, 8 // 4] offset 0, the idle and streaming rows at dummy (0, 0)
+    targets = {(12, 1), (9, 0), (0, 0)}
+    n_pools = 0
+    for before, after, ref in zip(
+            *(jax.tree.leaves(t, is_leaf=kvpool._cache_leaf)
+              for t in (state, got_state, want_state))):
+        for a, b, r in zip(before, after, ref):
+            a, b, r = np.asarray(a), np.asarray(b), np.asarray(r)
+            if not isinstance(before, KVCache):
+                np.testing.assert_array_equal(b, r)
+                continue
+            # the idle and streaming rows both write dummy block 0 at
+            # offset 0, and which duplicate write lands is unspecified
+            np.testing.assert_array_equal(b[..., 1:, :, :, :],
+                                          r[..., 1:, :, :, :])
+            changed = (a != b).any(axis=(-2, -1)).reshape(-1, n_blocks, bs)
+            for u in range(changed.shape[0]):
+                assert set(zip(*np.nonzero(changed[u]))) == targets
+            n_pools += 1
+    assert n_pools == 2 * (cfg.block_pattern.count("g")
+                           + cfg.tail_pattern.count("g"))
+
+
 @pytest.mark.slow
 def test_engine_greedy_matches_manual_decode(setup):
     """Engine tokens == hand-rolled prefill+argmax decode for one request."""

@@ -10,6 +10,7 @@ never at import, so every test worker collects the same tests and only the
 worker that runs this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,11 +21,14 @@ from jax.sharding import Mesh, SingleDeviceSharding
 from repro.configs.base import get_config
 from repro.core import gridshard
 from repro.core import scenarios as sc
+from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.partition_sweep import partition_sweep_batched
 from repro.kernels.rglru_scan import rglru_scan_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
+from repro.models import transformer
+from repro.serving import kvpool
 
 pytestmark = pytest.mark.filterwarnings("ignore")
 
@@ -56,12 +60,19 @@ def compile_for(one_chip):
     cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
-    def compile_(fn, *shapes, sharding=one_chip):
-        """Each shape is a tuple of ints (bf16) or a (shape, dtype) pair."""
-        args = [jax.ShapeDtypeStruct(*(s if isinstance(s[0], tuple)
-                                       else (s, jnp.bfloat16)),
-                                     sharding=sharding) for s in shapes]
-        return jax.jit(fn).lower(*args).compile().as_text()
+    def compile_(fn, *shapes, sharding=one_chip, donate=()):
+        """Each shape is a tuple of ints (bf16), a (shape, dtype) pair, or
+        a pytree of arrays or ``ShapeDtypeStruct``s."""
+        def spec(s):
+            if not isinstance(s, tuple):
+                return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=sharding), s)
+            return jax.ShapeDtypeStruct(
+                *(s if isinstance(s[0], tuple) else (s, jnp.bfloat16)),
+                sharding=sharding)
+        args = [spec(s) for s in shapes]
+        return jax.jit(fn, donate_argnums=donate).lower(
+            *args).compile().as_text()
 
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -152,3 +163,42 @@ def test_rglru_scan_recurrentgemma_widths(compile_for):
     text = compile_for(lambda x, a, rs: rglru_scan_pallas(x, a, reset=rs),
                        shape, shape, ((2, 2048), jnp.bool_))
     assert "tpu_custom_call" in text and "%rglru_scan" in text
+
+
+def test_paged_decode_writes_the_donated_pool_in_place(compile_for):
+    """The engine's decode program at the burst cell's shapes (qwen3-0.6b,
+    32 slots, 641 blocks of 16, table width 20), its state donated as the
+    engine donates it: no copy, temporary buffer or dynamic-update-slice of
+    a KV pool's shape, no per-layer slice of one, and every pool parameter
+    aliased to its output (the new rows are scattered in place).  The
+    decode kernel is the Pallas one, as on the chip."""
+    slots, n_blocks, bs, width = 32, 641, 16, 20
+    params = jax.eval_shape(
+        lambda: transformer.init_params(jax.random.PRNGKey(0), QWEN))
+    state = jax.eval_shape(lambda p: kvpool.init_decode_state(
+        QWEN, p, slots, n_blocks, bs), params)
+    ops.set_impl("pallas")
+    try:
+        text = compile_for(
+            lambda p, st, t, bt, sl: transformer.decode_step_paged(
+                p, QWEN, st, t, bt, sl),
+            params, state, ((slots,), jnp.int32),
+            ((slots, width), jnp.int32), ((slots,), jnp.int32), donate=1)
+    finally:
+        ops.set_impl("auto")
+    assert "%decode_attention" in text
+    tail = f"{n_blocks},{bs},{QWEN.n_kv},{QWEN.resolved_head_dim}]"
+    pool = f"bf16[{QWEN.n_units},{tail}"
+    insts = re.findall(r"= (bf16\[[\d,]*\])\S* ([\w-]+)\((.*)", text)
+    pool_ops = [(op, rest) for shape, op, rest in insts if shape == pool]
+    assert pool_ops
+    for op, rest in pool_ops:
+        assert op not in ("copy", "copy-start", "dynamic-update-slice"), op
+        assert "AllocateBuffer" not in rest
+    assert not [op for shape, op, _ in insts if shape == f"bf16[{tail}"]
+    entry = text[text.index("\nENTRY"):]
+    pool_params = set(re.findall(re.escape(pool) + r"\S* parameter\((\d+)\)",
+                                 entry))
+    aliased = set(re.findall(r"\(\s*(\d+), \{\}, (?:may|must)-alias\)",
+                             text.split("\n", 1)[0]))
+    assert len(pool_params) == 2 and pool_params <= aliased
