@@ -44,8 +44,8 @@ def main(argv=None) -> int:
         seqs = [(r.req.prompt, list(r.req.out))
                 for r in correct.sample(finished, seed)]
         size = (cfg, mix["s_max"], mix["output"]["max"], seqs)
-        prog = correct.served_gaps(w, *size)
-        ctrl = correct.served_gaps(w, *size, control=True)
+        prog = correct.served_gaps(cell["family"], w, *size)
+        ctrl = correct.served_gaps(cell["family"], w, *size, control=True)
         print(json.dumps({
             "seed": seed, "finished": len(finished), "tokens": len(prog),
             "program_max_gap": float(prog.max()),

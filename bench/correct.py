@@ -3,13 +3,14 @@ reference, once the window has closed.
 
 A sample drawn from the seed of the requests the window finished -- the
 longest among them, then others until ``SAMPLE_TOKENS`` served tokens --
-is run through ``reference/qwen3.py`` over each prompt with its served
-tokens.  For every served token the number compared is the gap by which
-the reference's logit of that token lies below the reference's best logit
-at that position; the check holds the widest gap in the sample to the
-cell's limit (``limits/<workload>.json``).  Greedy serving would pick the
-reference's best everywhere if it computed exactly; bfloat16 serving may
-pick a near-tie, never a token far below the best.
+is run through the plain reference of the cell's model family (its
+``logits``) over each prompt with its served tokens.  For every served
+token the number compared is the gap by which the reference's logit of
+that token lies below the reference's best logit at that position; the
+check holds the widest gap in the sample to the cell's limit
+(``limits/<workload>.json``).  Greedy serving would pick the reference's
+best everywhere if it computed exactly; bfloat16 serving may pick a
+near-tie, never a token far below the best.
 
 Besides, every finished request must carry exactly the tokens it asked
 for, and no request may be left unfinished after the drain.
@@ -21,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import schedule
-from bench.reference import qwen3
 
 SAMPLE_TOKENS = 512
 
@@ -51,11 +51,12 @@ def _gap_of(ref, toks):
     return jnp.max(ref, axis=1) - picked
 
 
-def served_gaps(w, cfg: dict, s_max: int, p_max: int, seqs,
+def served_gaps(family, w, cfg: dict, s_max: int, p_max: int, seqs,
                 control: bool = False) -> np.ndarray:
     """Gaps of every served token of ``seqs`` (pairs of prompt and served
-    tokens).  With ``control``, the tokens are those that the fp8
-    reference puts first at each position instead of the served ones."""
+    tokens) under ``family``'s reference.  With ``control``, the tokens
+    are those that the fp8 reference puts first at each position instead
+    of the served ones."""
     gaps = []
     for prompt, out in seqs:
         n, m = len(prompt), len(out)
@@ -64,9 +65,9 @@ def served_gaps(w, cfg: dict, s_max: int, p_max: int, seqs,
         tokens[0, n:n + m - 1] = out[:-1]
         rows = np.zeros((1, p_max), np.int32)
         rows[0, :m] = n - 1 + np.arange(m)
-        ref = qwen3.logits(w, cfg, tokens, rows)[0]
+        ref = family.logits(w, cfg, tokens, rows)[0]
         if control:
-            low = qwen3.logits(w, cfg, tokens, rows, precision="fp8")[0]
+            low = family.logits(w, cfg, tokens, rows, precision="fp8")[0]
             toks = jnp.argmax(low, axis=1).astype(jnp.int32)
         else:
             toks = np.zeros(p_max, np.int32)
@@ -76,14 +77,15 @@ def served_gaps(w, cfg: dict, s_max: int, p_max: int, seqs,
     return np.concatenate(gaps) if gaps else np.zeros(0)
 
 
-def check(w, cfg: dict, mix: dict, limits: dict, finished: list, *,
-          unfinished: int, seed: int) -> dict:
+def check(family, w, cfg: dict, mix: dict, limits: dict, finished: list,
+          *, unfinished: int, seed: int) -> dict:
     """Each number compared, beside its limit (None: nothing to compare,
     which fails)."""
     short = sum(len(r.req.out) != r.max_new for r in finished)
     picked = sample(finished, seed)
     seqs = [(np.asarray(r.req.prompt), list(r.req.out)) for r in picked]
-    gaps = served_gaps(w, cfg, mix["s_max"], mix["output"]["max"], seqs)
+    gaps = served_gaps(family, w, cfg, mix["s_max"], mix["output"]["max"],
+                       seqs)
     return {
         "max_logit_gap": {"value": float(gaps.max()) if len(gaps) else None,
                           "limit": float(limits["max_logit_gap"])},

@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import contextlib
 import gc
-import importlib.util
 import shutil
 import tempfile
 import time
@@ -28,9 +27,8 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from bench import (correct, flops, model, peaks, schedule, stats,
-                   trace_reduce)
-from bench.cell import BENCH
+from bench import correct, peaks, schedule, stats, trace_reduce
+from bench.cell import BENCH, load_file
 
 DRAIN_S = 60.0
 TRACE_S = 5.0
@@ -118,12 +116,7 @@ def _span(trace: bool, name: str, **kw):
 
 
 def _load_reader(name: str):
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}",
-        METRICS / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_file(METRICS / f"{name}.py", "bench_metric").read
 
 
 def read_metrics(metrics: list[dict], record: dict) -> dict:
@@ -216,14 +209,15 @@ def drive(engine, sched, seconds: float, Req, *,
 def serve(cell: dict, *, seed: int, seconds: float, trace: bool,
           t_proc: float) -> tuple[dict, dict]:
     """Set-up, the window and the drain.  Returns the run's record and the
-    weights; the engine is freed."""
+    weights; the engine is freed.  The cell's family makes the weights and
+    the engine's model."""
     from repro.serving.engine import Request as Req
     from repro.serving.engine import ServingEngine
 
-    cfg, mix = cell["config"], cell["traffic"]
+    cfg, mix, fam = cell["config"], cell["traffic"], cell["family"]
     device = jax.devices()[0]
-    w = model.init_weights(cfg, jax.random.key(schedule.weight_seed(seed)))
-    engine = ServingEngine(model.arch_config(cfg), model.engine_params(w),
+    w = fam.init_weights(cfg, jax.random.key(schedule.weight_seed(seed)))
+    engine = ServingEngine(fam.arch_config(cfg), fam.engine_params(w),
                            slots=mix["slots"], s_max=mix["s_max"])
     sched = schedule.build(mix, vocab=cfg["vocab_size"], seed=seed,
                            seconds=seconds)
@@ -242,7 +236,8 @@ def serve(cell: dict, *, seed: int, seconds: float, trace: bool,
     compiles.open = pauses.open = False
     mem = device.memory_stats() or {}
     record = {
-        "config": cfg, "traffic": mix, "seconds": float(seconds),
+        "config": cfg, "family": fam, "traffic": mix,
+        "seconds": float(seconds),
         "setup_s": steps.t_win - t_proc, "end_s": end,
         "peaks": peaks.lookup(device.device_kind),
         "requests": reqs, "steps": steps,
@@ -269,13 +264,13 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
     """One run: the result line's object."""
     record, w = serve(cell, seed=seed, seconds=seconds, trace=trace,
                       t_proc=t_proc)
-    cfg, mix = cell["config"], cell["traffic"]
+    cfg, mix, fam = cell["config"], cell["traffic"], cell["family"]
     reqs, steps = record["requests"], record["steps"]
     n = len(reqs)
     device = jax.devices()[0]
     finished = [r for r in reqs if r.done]
     t_check = time.perf_counter()
-    checks = correct.check(w, cfg, mix, cell["limits"], finished,
+    checks = correct.check(fam, w, cfg, mix, cell["limits"], finished,
                            unfinished=sum(not r.done for r in reqs),
                            seed=seed)
     t_check = time.perf_counter() - t_check
@@ -296,7 +291,7 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         dev["window_s"] = record["trace"]["window_s"]
         result["breakdown"] = record["trace"]["breakdown"]
     live = (stats.live_kv_tokens(record)[stats.window_steps(record)]
-            * flops.kv_bytes_per_token(cfg))
+            * fam.kv_bytes_per_token(cfg))
     result["window"] = {"requests_due": n, "steps": len(steps.t0),
                         "live_kv_bytes_mean": float(live.mean()),
                         "live_kv_bytes_max": int(live.max()),

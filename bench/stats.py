@@ -1,12 +1,11 @@
 """Arithmetic shared by the metric readers: tails over all requests due in
-the window, and the work of each engine step."""
+the window, and the work of each engine step, counted by the run's model
+family (``record["family"]``)."""
 from __future__ import annotations
 
 import math
 
 import numpy as np
-
-from bench import flops
 
 
 def tail(values, p: float) -> float | None:
@@ -49,25 +48,25 @@ def step_flops(record) -> np.ndarray:
     """Model FLOPs of the tokens each step produced: a first token carries
     its whole prompt (a chunked prompt is counted in the step that
     finished it), a later token one decode step over its context."""
-    cfg = record["config"]
+    cfg, fam = record["config"], record["family"]
     out = np.zeros(len(record["steps"].t0))
     for r in record["requests"]:
         for j, k in enumerate(r.token_steps):
-            out[k] += (flops.prefill_flops(cfg, r.prompt_len) if j == 0 else
-                       flops.decode_token_flops(cfg, r.prompt_len + j))
+            out[k] += (fam.prefill_flops(cfg, r.prompt_len) if j == 0 else
+                       fam.decode_token_flops(cfg, r.prompt_len + j))
     return out
 
 
 def decode_attn_work(record) -> tuple[np.ndarray, np.ndarray]:
     """Per step, the FLOPs and bytes decode attention needed for the
     tokens the step decoded."""
-    cfg = record["config"]
+    cfg, fam = record["config"], record["family"]
     n = len(record["steps"].t0)
     f, b = np.zeros(n), np.zeros(n)
     for r in record["requests"]:
         for j, k in enumerate(r.token_steps[1:], start=1):
-            f[k] += flops.decode_attn_flops(cfg, r.prompt_len + j)
-            b[k] += flops.decode_attn_bytes(cfg, r.prompt_len + j)
+            f[k] += fam.decode_attn_flops(cfg, r.prompt_len + j)
+            b[k] += fam.decode_attn_bytes(cfg, r.prompt_len + j)
     return f, b
 
 

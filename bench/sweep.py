@@ -40,14 +40,14 @@ def main(argv=None) -> int:
         return 2
     import jax
     from bench import cell as cell_mod
-    from bench import harness, model, schedule, stats
+    from bench import harness, schedule, stats
     from repro.serving.engine import Request, ServingEngine
 
     cell = cell_mod.load(args.workload)
-    cfg, mix = cell["config"], cell["traffic"]
+    cfg, mix, fam = cell["config"], cell["traffic"], cell["family"]
     rates = [float(r) for r in args.rates.split(",")]
-    w = model.init_weights(cfg, jax.random.key(args.seed))
-    engine = ServingEngine(model.arch_config(cfg), model.engine_params(w),
+    w = fam.init_weights(cfg, jax.random.key(args.seed))
+    engine = ServingEngine(fam.arch_config(cfg), fam.engine_params(w),
                            slots=mix["slots"], s_max=mix["s_max"])
     scheds = {r: schedule.build(
         dict(mix, arrivals={"kind": "poisson", "rate_per_s": r}),

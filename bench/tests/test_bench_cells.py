@@ -8,7 +8,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import cell, model, schedule
+from bench import cell, schedule
 
 ROOT = Path(__file__).resolve().parents[2]
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -33,7 +33,7 @@ def test_cells_load(alone, name):
     c = cell.load(name, root=alone)
     assert c["chips"] in (1, 4)
     cfg, mix = c["config"], c["traffic"]
-    assert model.arch_config(cfg).n_layers == cfg["num_hidden_layers"]
+    assert c["family"].arch_config(cfg).n_layers == cfg["num_hidden_layers"]
     assert c["limits"]["max_logit_gap"] > 0
     sched = schedule.build(mix, vocab=cfg["vocab_size"], seed=1,
                            seconds=SPEC["run_seconds"])

@@ -1,5 +1,5 @@
-"""Percentiles with missing requests, the FLOP and byte counts, and the
-table of peaks."""
+"""Percentiles with missing requests, the FLOP and byte counts of each
+configuration's family, and the table of peaks."""
 import json
 import math
 from pathlib import Path
@@ -7,13 +7,17 @@ from pathlib import Path
 import jax
 import pytest
 
-from bench import flops, harness, model, peaks, stats
+from bench import cell, harness, peaks, stats
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 
 
 def _cfg(name):
     return json.loads((CONFIGS / f"{name}.json").read_text())
+
+
+def _family(cfg):
+    return cell.family(cfg["model_type"])
 
 
 def _record(token_steps, done, end_s=10.0):
@@ -90,34 +94,36 @@ def test_live_kv_rows():
     ("qwen3-0.6b", 596_049_920, 112), ("qwen3-4b", 4_022_468_096, 144)])
 def test_counts_match_the_served_weights(name, params, kv_kib):
     cfg = _cfg(name)
+    fam = _family(cfg)
     shapes = jax.eval_shape(
-        lambda k: model.engine_params(model.init_weights(cfg, k)),
+        lambda k: fam.engine_params(fam.init_weights(cfg, k)),
         jax.random.key(0))
-    assert flops.param_count(cfg) == params == sum(
+    assert fam.param_count(cfg) == params == sum(
         x.size for x in jax.tree.leaves(shapes))
-    assert flops.kv_bytes_per_token(cfg) == kv_kib * 1024
+    assert fam.kv_bytes_per_token(cfg) == kv_kib * 1024
 
 
 @pytest.mark.parametrize("name", ["qwen3-0.6b", "qwen3-4b"])
 def test_flops_and_bytes(name):
     cfg = _cfg(name)
+    fam = _family(cfg)
     d, v, n = cfg["hidden_size"], cfg["vocab_size"], cfg["num_hidden_layers"]
     h, kv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
                  cfg["head_dim"])
-    matmul = flops.param_count(cfg) - v * d - n * (2 * d + 2 * hd) - d
-    assert flops.layer_matmul_params(cfg) * n == matmul
+    matmul = fam.param_count(cfg) - v * d - n * (2 * d + 2 * hd) - d
+    assert fam.layer_matmul_params(cfg) * n == matmul
     # one decoded token: two FLOPs per matmul weight, the tied head, and
     # attention over its context
-    assert flops.decode_token_flops(cfg, 100) == (
+    assert fam.decode_token_flops(cfg, 100) == (
         2 * matmul + 2 * v * d + n * 4 * h * hd * 100)
     # a one-token prompt is one decode step at context 1
-    assert flops.prefill_flops(cfg, 1) == flops.decode_token_flops(cfg, 1)
+    assert fam.prefill_flops(cfg, 1) == fam.decode_token_flops(cfg, 1)
     # a prompt costs its tokens' matmuls, a causal triangle, one head
     p = 64
-    assert flops.prefill_flops(cfg, p) == (
+    assert fam.prefill_flops(cfg, p) == (
         2 * matmul * p + n * 4 * h * hd * p * (p + 1) // 2 + 2 * v * d)
-    assert flops.decode_attn_flops(cfg, 100) == n * 4 * h * hd * 100
-    assert flops.decode_attn_bytes(cfg, 100) == n * 2 * (
+    assert fam.decode_attn_flops(cfg, 100) == n * 4 * h * hd * 100
+    assert fam.decode_attn_bytes(cfg, 100) == n * 2 * (
         2 * h * hd + 2 * 100 * kv * hd)
 
 

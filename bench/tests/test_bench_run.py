@@ -36,7 +36,8 @@ def _tiny_cell():
            "output": {"kind": "lognormal", "median": 8, "sigma": 0.5,
                       "min": 2, "max": 16}}
     spec = cell.benchmark()
-    return {"name": "tiny", "chips": 1, "config": cfg, "traffic": mix,
+    return {"name": "tiny", "chips": 1, "config": cfg,
+            "family": cell.family("qwen3"), "traffic": mix,
             "limits": {"max_logit_gap": LIMIT},
             "end_to_end": spec["end_to_end"], "per_layer": spec["per_layer"]}
 
@@ -85,8 +86,8 @@ def test_fp8_control_is_not_correct(cpu_peaks):
     seqs = [(r.req.prompt, list(r.req.out))
             for r in correct.sample(finished, 3)]
     size = (c["config"], c["traffic"]["s_max"], 16, seqs)
-    program = correct.served_gaps(w, *size)
-    control = correct.served_gaps(w, *size, control=True)
+    program = correct.served_gaps(c["family"], w, *size)
+    control = correct.served_gaps(c["family"], w, *size, control=True)
     assert len(program) == len(control) > 100
     assert program.max() <= LIMIT < control.max()
 

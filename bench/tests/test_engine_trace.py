@@ -11,6 +11,7 @@ from types import SimpleNamespace
 
 import pytest
 
+from bench import cell
 from bench import engine_trace as et
 from bench import harness
 from bench import trace_reduce as tr
@@ -219,8 +220,8 @@ def test_traced_cpu_run_reads_the_scheduler(monkeypatch):
                       "min": 4, "max": 80},
            "output": {"kind": "lognormal", "median": 8, "sigma": 0.5,
                       "min": 2, "max": 16}}
-    record, _ = harness.serve({"config": cfg, "traffic": mix}, seed=5,
-                              seconds=2.0, trace=True,
+    c = {"config": cfg, "family": cell.family("qwen3"), "traffic": mix}
+    record, _ = harness.serve(c, seed=5, seconds=2.0, trace=True,
                               t_proc=time.perf_counter())
     phases = record["trace"]["phases"]
     assert phases and all("engine.step" in ph for ph in phases.values())
