@@ -13,6 +13,10 @@ engine adds: the host spans it enters inside ``engine.step()``
   span's length less the part its child spans cover);
 * ``programs``, per step: the device time of each program that started in
   it, by name;
+* ``kernels``, inside each entry of ``steps``: the device time of the
+  Pallas calls that started in the step, by kernel name (``kernel_name``;
+  an unnamed kernel is ``pallas``), which adds up to the step's
+  ``kernel_ns``;
 * ``sync_lag_ns``, per step that ran ``decode_paged``: the end of the
   step's last ``engine.sync`` less the end of that program's last
   operation -- the runtime's round trip from the decode's end to its ids
@@ -25,9 +29,10 @@ operation), else to the innermost host span open over it, else to
 ``host.other``.  A program JAX could not name (``jit__lambda``) is kept
 under ``programs`` as ``_lambda`` but takes no idle time.
 
-The four readers at the end (``READERS``) take a run's record whose
-``trace`` is this reduction.  The harness records ``trace_reduce``'s, so
-they are not yet metrics of the benchmark.
+The harness reduces every traced run with ``reduce_dir``.  The four
+readers at the end are per-layer metrics of the benchmark: each has its
+file under ``bench/metrics/``.  Times per step are in ns and, on several
+chips, averaged over them.
 """
 from __future__ import annotations
 
@@ -51,6 +56,7 @@ PREFILL_PROGRAMS = ("prefill", "prefill_chunk", "commit_prefill",
 _OP = re.compile(r"%([\w.\-]+?)(?:\.\d+)* = (\(.*?\)|\S+?)(?:\{[^}]*\})? "
                  r"([\w\-]+)\(")
 _PROGRAM = re.compile(r"(?:jit_)?(.*?)(?:\(\d+\))?$")
+_KERNEL = re.compile(r"pallas ([\w.\-]+) ")
 
 
 def label(hlo: str) -> str:
@@ -68,6 +74,14 @@ def label(hlo: str) -> str:
                 else f"pallas {m.group(1)}")
     shape = re.sub(r"\{[^}]*\}", "", m.group(2))
     return f"{kind} {shape[:60]}"
+
+
+def kernel_name(op: str) -> str:
+    """The kernel's name in a Pallas operation's ``label``: 'pallas
+    decode_attention bf16[8,8,2,128]' -> 'decode_attention'; 'pallas' for
+    an unnamed kernel ('pallas bf16[...]')."""
+    m = _KERNEL.match(op)
+    return m.group(1) if m else "pallas"
 
 
 def program_name(module: str) -> str:
@@ -185,6 +199,14 @@ def reduce(ev: dict, last_step: int | None = None, top: int = 10) -> dict:
         i = int(np.searchsorted(starts, t, side="right")) - 1
         return steps[i][3] if i >= 0 and t < steps[i][2] else None
 
+    kernels: dict[int, dict[str, float]] = {k: {} for k in out["steps"]}
+    for name, s, e, _ in dev:
+        k = step_of(s) if name.startswith("pallas ") else None
+        if k is not None:
+            n = kernel_name(name)
+            kernels[k][n] = kernels[k].get(n, 0.0) + (e - s) / chips
+    for k, step in out["steps"].items():
+        step["kernels"] = kernels[k]
     pieces = innermost(host)
     phases: dict[int, dict[str, float]] = {k: {} for k in out["steps"]}
     for s, e, name in pieces:
@@ -302,7 +324,3 @@ def prefill_program_s_p90(record):
     if not t:
         return None
     return stats.tail(t, 90) / 1e9
-
-
-READERS = (sched_host_s_p50, sync_lag_s_p50, decode_program_s_p50,
-           prefill_program_s_p90)

@@ -12,8 +12,11 @@ unbounded in every tail.
 With ``trace`` on, the same run also records a profiler trace from
 ``TRACE_S`` seconds before the window closes to the end of the drain, and
 each call into the engine is a named host span in it (``bench.submit``,
-``bench.step``, ``bench.bookkeeping``, ``bench.wait``).  The device
-metrics read the traced steps that started inside the window.
+``bench.step``, ``bench.bookkeeping``, ``bench.wait``), beside the
+engine's own ``engine.*`` spans and named programs and kernels.  After the
+drain ``bench/engine_trace.py`` reduces the trace into the record's
+``trace``; the device metrics read the traced steps that started inside
+the window.
 """
 from __future__ import annotations
 
@@ -27,7 +30,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from bench import correct, peaks, schedule, stats, trace_reduce
+from bench import correct, engine_trace, peaks, schedule, stats
 from bench.cell import BENCH, load_file
 
 DRAIN_S = 60.0
@@ -252,7 +255,7 @@ def serve(cell: dict, *, seed: int, seconds: float, trace: bool,
     try:
         if traced:          # the traced steps that started in the window
             last = int(np.searchsorted(steps.t0, seconds)) - 1
-            record["trace"] = trace_reduce.reduce_dir(tmp / "window", last)
+            record["trace"] = engine_trace.reduce_dir(tmp / "window", last)
     finally:
         if tmp is not None:
             shutil.rmtree(tmp, ignore_errors=True)

@@ -70,6 +70,32 @@ def decode_attn_work(record) -> tuple[np.ndarray, np.ndarray]:
     return f, b
 
 
+def kernel_roofline(record, kernel: str, flops, bytes_,
+                    steps) -> float | None:
+    """The named kernel's share of its roofline, in percent, over the
+    traced steps among ``steps`` in which it ran: the least time each step
+    needs, the larger of its FLOPs over the peak rate and its bytes over the
+    peak bandwidth (``flops`` and ``bytes_``: per step, counted by the
+    family), summed, over the kernel's device time in those steps (the
+    step's ``kernels[kernel]``).  None where there is no trace, the kernel
+    did not run or the steps need no work."""
+    tr = record["trace"]
+    if tr is None:
+        return None
+    steps, pk = set(steps), record["peaks"]
+    need = kern = 0.0
+    for k, s in tr["steps"].items():
+        t = s["kernels"].get(kernel, 0.0)
+        if k not in steps or t <= 0:
+            continue
+        need += max(flops[k] / pk["bf16_flops_per_s"],
+                    bytes_[k] / pk["hbm_bytes_per_s"])
+        kern += t / 1e9
+    if kern <= 0 or need <= 0:
+        return None
+    return 100.0 * need / kern
+
+
 def admitting_steps(record) -> set[int]:
     return {r.admit_step for r in record["requests"]
             if r.admit_step is not None}

@@ -2,8 +2,11 @@
 and the files under ``bench/`` alone, and the file keeps to the shape the
 harness reads."""
 import json
+import os
 import re
 import shutil
+import subprocess
+import sys
 from pathlib import Path
 
 import pytest
@@ -75,3 +78,57 @@ def test_benchmark_file_shape():
                                "program_counter", "host_clock")
     layers = {m["name"]: m["layer"] for m in SPEC["per_layer"]}
     assert all(0 < len(v) <= 200 and "\n" not in v for v in layers.values())
+
+
+# a family's kernel metric, as a model_config change would bring it: one
+# reader file and one per_layer entry, nothing else edited
+SSD_READER = '''"""Kernels: ssd_scan's share of its roofline."""
+import numpy as np
+
+from bench import stats
+
+
+def read(record):
+    work = np.asarray([1e9, 2e9])          # FLOPs per step
+    return stats.kernel_roofline(record, "ssd_scan", work, work / 10, [0, 1])
+'''
+READ_IN_COPY = '''
+import json, sys
+from bench import cell, harness
+c = cell.load(sys.argv[1])
+steps = {0: {"kernels": {"ssd_scan": 2e6, "decode_attention": 9e6}},
+         1: {"kernels": {"ssd_scan": 2e6}}}
+record = {"trace": {"steps": steps},
+          "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}}
+mine = [m for m in c["per_layer"] if m["name"] == "ssd_scan_roofline"]
+print(harness.METRICS)
+print(json.dumps(harness.read_metrics(mine, record)))
+'''
+
+
+def test_family_kernel_metric_is_new_files_only(alone, tmp_path):
+    d = tmp_path / "copy"
+    shutil.copytree(alone, d)
+    spec = json.loads((d / "BENCHMARK.json").read_text())
+    names = [w["name"] for w in spec["workloads"]]
+    spec["per_layer"].append({
+        "name": "ssd_scan_roofline", "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "kernels (kernels/ssd_scan.py)",
+        "moves": "itl_p95_s", "workloads": names})
+    (d / "BENCHMARK.json").write_text(json.dumps(spec, indent=1))
+    (d / "bench" / "metrics" / "ssd_scan_roofline.py").write_text(SSD_READER)
+    for name in names:
+        assert "ssd_scan_roofline" in {
+            m["name"] for m in cell.load(name, root=d)["per_layer"]}
+    # the copy's own harness finds the reader by the metric's name: 1 ms
+    # and 2 ms of work in 4 ms of the kernel
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([str(d), str(ROOT / "src")]))
+    p = subprocess.run([sys.executable, "-c", READ_IN_COPY, names[0]],
+                       cwd=d, env=env, capture_output=True, text=True,
+                       timeout=120)
+    assert p.returncode == 0, p.stderr
+    where, got = p.stdout.strip().splitlines()[-2:]
+    assert Path(where) == d / "bench" / "metrics"
+    got = json.loads(got)
+    assert got == {"ssd_scan_roofline": {"value": 75.0, "unit": "%"}}

@@ -72,7 +72,8 @@ def test_trace_run_reports_per_layer_metrics(cpu_peaks):
     assert r["correct"] is True
     names = {m["name"] for m in cell.benchmark()["per_layer"]}
     assert set(r["metrics"]) <= names
-    for m in ("admit_tick_s_p90", "tick_s_p50", "step_mfu"):
+    for m in ("admit_tick_s_p90", "tick_s_p50", "step_mfu",
+              "sched_host_s_p50"):
         assert m in r["metrics"]
     assert {"busy_s", "window_s"} <= set(r["device"])
     assert set(r["breakdown"]) == {"device_ops", "idle_gaps"}

@@ -1,7 +1,8 @@
 """The reduction of the engine's spans and named programs, on hand-made
-events and on two traces recorded on one TPU v5e.  Both traces hold three
-steps of qwen3-0.6b at 32 slots, s_max 320: ``decode_steps`` from before
-the engine had spans or named programs, ``engine_steps`` with them (its
+events and on two traces recorded on one TPU v5e, and the readers that
+take it from a run's record.  Both traces hold three decoding steps of
+qwen3-0.6b at 32 slots, s_max 320: ``decode_steps`` from before the engine
+had spans or named programs and kernels, ``engine_steps`` with them (its
 middle step admits a whole 20-token prompt)."""
 import gzip
 import json
@@ -9,11 +10,12 @@ import time
 from pathlib import Path
 from types import SimpleNamespace
 
+import numpy as np
 import pytest
 
 from bench import cell
 from bench import engine_trace as et
-from bench import harness
+from bench import harness, stats
 from bench import trace_reduce as tr
 
 FIXTURES = Path(__file__).parent / "fixtures"
@@ -67,7 +69,7 @@ def test_reduce_puts_idle_down_to_innermost_span_or_program():
     assert idle == pytest.approx(out["window_s"] - out["busy_s"], rel=1e-9)
     # the keys trace_reduce gives read what they read there
     assert out["steps"][0] == {"span_ns": 100, "busy_ns": 43.0,
-                               "kernel_ns": 0.0}
+                               "kernel_ns": 0.0, "kernels": {}}
 
 
 def test_reduce_programs_phases_and_sync_lag():
@@ -101,6 +103,10 @@ def test_label_names_a_named_kernel():
                 '"tpu_custom_call"',
                 "%fusion.99 = s32[32]{0:T(128)S(1)} fusion(s32[1,32] %p)"):
         assert et.label(hlo) == tr.label(hlo)
+    assert et.kernel_name(et.label(text)) == "decode_attention"
+    assert et.kernel_name("pallas ssd_scan (bf16[1,2], f32[3])") == "ssd_scan"
+    assert et.kernel_name("pallas bf16[32,8,2,128]") == "pallas"
+    assert et.kernel_name("pallas (bf16[1,2], f32[3])") == "pallas"
     assert et.program_name("jit_decode_paged(1234)") == "decode_paged"
     assert et.program_name("jit__lambda(99)") == et.UNNAMED_PROGRAM
 
@@ -112,11 +118,27 @@ def test_reduce_keeps_trace_reduce_keys(name):
     xspace = _recorded(name)
     old = tr.reduce(tr.events(xspace))
     new = et.reduce(et.events(xspace))
-    assert new["steps"] == old["steps"]
+    assert {k: {n: v for n, v in s.items() if n != "kernels"}
+            for k, s in new["steps"].items()} == old["steps"]
     assert (new["busy_s"], new["window_s"]) == (old["busy_s"],
                                                 old["window_s"])
     assert ([v for _, v in new["breakdown"]["device_ops"]] ==
             [v for _, v in old["breakdown"]["device_ops"]])
+
+
+@pytest.mark.parametrize("name", ["decode_steps", "engine_steps"])
+def test_step_kernels_add_up_to_kernel_ns(name):
+    out = et.reduce(et.events(_recorded(name)))
+    assert sorted(out["steps"]) == [0, 1, 2]
+    for s in out["steps"].values():
+        assert s["kernels"] and sum(s["kernels"].values()) == s["kernel_ns"]
+    kernels = [set(out["steps"][k]["kernels"]) for k in range(3)]
+    if name == "decode_steps":      # its kernels had no names
+        assert kernels == [{"pallas"}] * 3
+    else:                           # every step decodes; the middle one
+        assert kernels == [{"decode_attention"},   # also admits a prompt
+                           {"decode_attention", "flash_attention"},
+                           {"decode_attention"}]
 
 
 def test_reduce_recorded_engine_spans():
@@ -151,6 +173,55 @@ def test_reduce_recorded_engine_spans():
     named = sum(v for n, v in gaps.items()
                 if n.startswith(("engine.", "device:")))
     assert named >= 0.9 * (named + gaps.get("bench.step", 0.0))
+
+
+def test_kernel_roofline_arithmetic():
+    steps = {0: {"kernels": {"ssd_scan": 4e6, "pallas": 1e6}},
+             1: {"kernels": {"ssd_scan": 2e6}},
+             2: {"kernels": {"decode_attention": 5e6}},
+             3: {"kernels": {"ssd_scan": 1e9}}}
+    rec = {"trace": {"steps": steps},
+           "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}}
+    flops = np.asarray([2e9, 1e9, 0, 0])
+    bytes_ = np.asarray([1e8, 3e8, 0, 0])
+
+    def read(kernel="ssd_scan", steps=(0, 1, 2)):
+        return stats.kernel_roofline(rec, kernel, flops, bytes_, steps)
+
+    # step 0 needs max(2 ms, 1 ms), step 1 max(1 ms, 3 ms): 5 ms of the
+    # kernel's 6 ms; step 2 did not run it, step 3 is not asked for
+    assert read() == pytest.approx(100.0 * 5 / 6)
+    assert read(steps=[1]) == pytest.approx(100.0 * 3 / 2)
+    assert read("decode_attention") is None       # no work counted
+    assert read("rglru_scan") is None             # the kernel never ran
+    assert read(steps=[]) is None
+    rec["trace"] = None
+    assert read() is None
+
+
+def test_decode_attn_roofline_reads_its_own_kernel():
+    """On the recorded steps it reads what it read when it took every
+    Pallas call in them (``kernel_ns``), since the one step in which another
+    kernel ran admitted a prompt and is left out."""
+    out = et.reduce(et.events(_recorded("engine_steps")))
+    cfg = json.loads((CONFIGS / "qwen3-0.6b.json").read_text())
+    # 31 requests admitted before the traced steps decode through them;
+    # one more is admitted in step 1 and gets its first token there
+    reqs = [SimpleNamespace(admit_step=None, prompt_len=60 + 4 * i,
+                            token_steps=[0, 1, 2]) for i in range(31)]
+    reqs.append(SimpleNamespace(admit_step=1, prompt_len=20,
+                                token_steps=[1, 2]))
+    rec = {"trace": out, "config": cfg, "family": cell.family("qwen3"),
+           "requests": reqs, "steps": SimpleNamespace(t0=[0.0] * 3),
+           "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}}
+    read = harness._load_reader("decode_attn_roofline")
+    named = read(rec)
+    assert 0 < named < 100
+    for s in out["steps"].values():
+        s["kernels"] = {"decode_attention": s["kernel_ns"]}
+    assert read(rec) == named
+    rec["trace"] = None
+    assert read(rec) is None
 
 
 def _traced(parent=False):
@@ -190,7 +261,7 @@ def _traced(parent=False):
 ])
 def test_engine_trace_readers(name, want):
     read = getattr(et, name)
-    assert read in et.READERS
+    assert harness._load_reader(name) is read      # the metric's file
     assert read(_traced()) == pytest.approx(want)
     # a trace without the engine's spans and program names, trace_reduce's
     # reduction, or no trace: nothing to read
@@ -203,11 +274,10 @@ def test_engine_trace_readers(name, want):
 
 
 def test_traced_cpu_run_reads_the_scheduler(monkeypatch):
-    """A traced run of a small qwen3 on the CPU, its trace reduced by this
-    module in place of ``trace_reduce``: the engine's spans give the
-    scheduler's host time; the CPU has no TPU plane, so no program time
-    and no lag."""
-    monkeypatch.setattr(harness.trace_reduce, "reduce_dir", et.reduce_dir)
+    """A traced run of a small qwen3 on the CPU, its trace reduced by the
+    harness with this module: the engine's spans give the scheduler's host
+    time; the CPU has no TPU plane, so no program or kernel time and no
+    lag."""
     monkeypatch.setattr(harness.peaks, "lookup", lambda kind: {
         "bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11})
     base = json.loads((CONFIGS / "qwen3-0.6b.json").read_text())
@@ -227,6 +297,7 @@ def test_traced_cpu_run_reads_the_scheduler(monkeypatch):
     assert phases and all("engine.step" in ph for ph in phases.values())
     assert any("engine.dispatch" in ph and "engine.sync" in ph
                for ph in phases.values())
+    assert all(s["kernels"] == {} for s in record["trace"]["steps"].values())
     assert et.sched_host_s_p50(record) > 0
     assert et.decode_program_s_p50(record) is None
     assert et.sync_lag_s_p50(record) is None

@@ -15,11 +15,9 @@ TPU plane).  ``reduce`` turns them into what the metrics read:
   output shape, and the idle time inside the window by the host span that
   was open (``host.other`` where none was).
 
-The engine's programs are all ``jit`` of lambdas and both Pallas kernels
-are named ``_kernel``, so an operation's name tells neither its program
-nor its kernel; the reader of ``decode_attn_roofline`` keeps to steps in
-which no request was admitted, where the decode kernel is the only Pallas
-call.
+This reduction reads neither the engine's own spans nor the names of its
+programs and kernels: ``bench/engine_trace.py``, which the harness calls,
+builds on it and adds them.
 """
 from __future__ import annotations
 
